@@ -192,16 +192,21 @@ def smoke(out_dir: Optional[str] = None, seed: Optional[int] = None) -> Dict:
     rng = np.random.default_rng(seed)
     t0 = time.time()
 
-    print("== golden (uninterrupted) run ==")
-    gold = chaos.golden_run(**SMOKE_KW)
-
-    print("== chaos drill: SIGKILL at a commit boundary, restart ==")
     wargs = [
         "--epochs", SMOKE_KW["epochs"],
         "--samples-per-class", SMOKE_KW["samples_per_class"],
         "--ticks", SMOKE_KW["num_ticks"],
         "--spb", SMOKE_KW["spb"],
     ]
+    # The golden and the drill both run in CPU-pinned workers, before this
+    # process touches JAX: both sides of the bitwise gate share a platform.
+    print("== golden (uninterrupted) run ==")
+    with tempfile.TemporaryDirectory() as d:
+        gold = chaos.golden_worker(
+            str(Path(d) / "ck"), str(Path(d) / "golden"), wargs
+        )
+
+    print("== chaos drill: SIGKILL at a commit boundary, restart ==")
     kill_at = int(rng.integers(1, 6))
     with tempfile.TemporaryDirectory() as d:
         out = str(Path(d) / "result")

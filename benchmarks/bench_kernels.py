@@ -361,8 +361,9 @@ def main(argv=None):
     infer_ratio_big = table_big["infer_streamed"] / table_big["infer_fused"]
     dens = density_traffic(d_meas, B_BIG)
     records, on_tpu = wall_clock(d_meas)
-    roofline = traffic.device_roofline()
-    bw_table = traffic.bandwidth_table(records, roofline)
+    # CPU records time the scan backend: they have no device roofline
+    roofline = traffic.device_roofline() if on_tpu else None
+    bw_table = traffic.bandwidth_table(records, roofline) if on_tpu else []
 
     print(f"measured braille density: {d_meas:.4f} ({d_source})")
     print("op,bytes_per_launch")
@@ -380,12 +381,13 @@ def main(argv=None):
         print(f"  {tag}: train dma {row['train_ratio']:.2f}x "
               f"(gate >= 1.4 for b*), infer dma {row['infer_ratio']:.2f}x "
               f"(gate >= 0.99 for b*)")
-    print("op,samples_per_s,achieved_GB/s,roofline_frac")
-    for row in bw_table:
-        frac = ("-" if row["roofline_frac"] is None
-                else f"{row['roofline_frac']:.3f}")
-        print(f"{row['op']},{row['samples_per_s']:.1f},"
-              f"{row['achieved_gbps']:.2f},{frac}")
+    if on_tpu:
+        print("op,samples_per_s,achieved_GB/s,roofline_frac")
+        for row in bw_table:
+            print(f"{row['op']},{row['samples_per_s']:.1f},"
+                  f"{row['achieved_gbps']:.2f},{row['roofline_frac']:.3f}")
+    else:
+        print(f"roofline: not measured (platform {jax.default_backend()})")
     print("event parity mismatches:", parity)
     print("allclose:", ", ".join(f"{k}={v:.2e}"
                                  for k, v in {**errs, **errs_big}.items()))

@@ -10,11 +10,11 @@ Primary mode (the revived one): consume the ``bandwidth_records`` that
                     (:func:`repro.kernels.traffic.device_roofline`)
   roofline frac   = achieved / roofline
 
-On hosts without an accelerator the device resolves to the CPU fallback and
-every ``roofline_frac`` is ``-``: interpret-mode wall-clock says nothing
-about kernel bandwidth, so the numbers are recorded for trend only (same
-policy as the CI serve gate).  Never crashes when no records exist — it
-prints how to produce them and moves on.
+On hosts without an accelerator the table is reported as not measured:
+interpret-mode wall-clock says nothing about kernel bandwidth, and
+:func:`~repro.kernels.traffic.device_roofline` knows no CPU row.  Never
+crashes when no records exist — it prints how to produce them and moves
+on.
 
 Auto-tune: instead of the hand-picked ``Bt`` guidance that used to live in
 ``docs/perf_tuning.md``, sweep the VMEM budget ladder, derive each budget's
@@ -72,10 +72,9 @@ def kernel_bandwidth_rows(records, roofline=None):
 def fmt_bandwidth(rows, roofline) -> str:
     hdr = (f"{'op':28s} {'samples/s':>10s} {'achieved_GB/s':>13s} "
            f"{'roofline_GB/s':>13s} {'frac':>6s}")
-    lines = [f"device: {roofline['kind']}  (measured={roofline['measured']})",
-             hdr, "-" * len(hdr)]
+    lines = [f"device: {roofline['kind']}", hdr, "-" * len(hdr)]
     for r in rows:
-        frac = "-" if r["roofline_frac"] is None else f"{r['roofline_frac']:.3f}"
+        frac = f"{r['roofline_frac']:.3f}"
         sps = r.get("samples_per_s")
         sps_s = f"{sps:.1f}" if isinstance(sps, (int, float)) else "-"
         lines.append(
@@ -89,8 +88,8 @@ def autotune(T, B, n_in, n_hid, n_out, density, vmem_total=VMEM_BYTES):
     """Per-op ``(Bt, vmem_budget)`` minimizing as-executed event-streaming
     bytes per sample at the measured density — the replacement for the
     hand-picked values the docs used to carry.  Pure analytics (the same
-    formulas the CI traffic gates enforce), so it runs identically on the
-    CPU fallback; ties break toward the smaller budget (leave VMEM spare)."""
+    formulas the CI traffic gates enforce), so it runs identically on any
+    host; ties break toward the smaller budget (leave VMEM spare)."""
     budgets = [m << 20 for m in _BUDGET_LADDER_MIB if (m << 20) <= vmem_total]
     ops = {
         "infer": (lambda vb: max_forward_tile(n_in, n_hid, n_out, vb),
@@ -220,14 +219,21 @@ def main(argv=None):
     ap.add_argument("--json-out")
     opts = ap.parse_args(argv)
 
-    roofline = traffic.device_roofline()
+    import jax
+
     records, payload = load_kernel_records(Path(opts.bench_dir))
+    on_tpu = jax.default_backend() == "tpu"
+    roofline = traffic.device_roofline() if on_tpu else None
     result = {"device": roofline, "rc": 0}
 
     if records:
-        rows = kernel_bandwidth_rows(records, roofline)
-        print(fmt_bandwidth(rows, roofline))
-        result["bandwidth"] = rows
+        if on_tpu:
+            rows = kernel_bandwidth_rows(records, roofline)
+            print(fmt_bandwidth(rows, roofline))
+            result["bandwidth"] = rows
+        else:
+            print(f"achieved-vs-roofline: not measured (platform "
+                  f"{jax.default_backend()})")
         tile = payload.get("tile", {})
         density = payload.get("event_density_braille")
         if tile and density is not None:

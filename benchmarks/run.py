@@ -63,6 +63,9 @@ def main(argv=None):
     ap.add_argument("--out-dir", default=".")
     opts = ap.parse_args(argv)
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import bench_cue, bench_kernels, bench_resources
     from benchmarks import bench_braille, bench_serve, roofline
 

@@ -88,10 +88,10 @@ from typing import Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import eprop
 from repro.core.quant import QuantizedMode, QuantSpec
 from repro.core.rsnn import RSNNConfig
@@ -107,6 +107,15 @@ from repro.kernels.rsnn_step import (
 
 # A traces pytree: the per-tick quantities of one forward pass, all (T, B, ·).
 Traces = Dict[str, jax.Array]
+
+
+class CompileError(RuntimeError):
+    """A launch's program failed to trace, lower or compile.
+
+    Deterministic — the same shapes fail the same way on every retry — so
+    it is a program error, never a transient device fault: the serving
+    supervisor re-raises it instead of restarting the lane and FAULTing
+    the requests (:class:`repro.serve.BatchedEngine`)."""
 
 
 def resolve_backend(backend: str) -> str:
@@ -338,6 +347,7 @@ class ExecutionBackend:
         else:
             self._mask = jnp.ones((cfg.n_hid, cfg.n_hid), jnp.float32)
         self._shapes: Dict[str, set] = {}
+        self._built: set = set()   # argument signatures already compiled
         sharded = self._batch_axes is not None
         self._jit_inference = jax.jit(
             self._inference_sharded if sharded else self._inference_impl
@@ -430,11 +440,33 @@ class ExecutionBackend:
 
     # ------------------------------------------------------------- plumbing
 
-    def _note(self, op: str, shape: Tuple[int, ...]) -> None:
-        # No launch-level batch guard any more: the kernels batch-tile
-        # internally (tile rows from tile_rows(), derived from the same
-        # bytes helpers) — any B runs, only a *tile* must fit VMEM.
+    def _launch(self, op: str, fn, shape: Tuple[int, ...], *args):
+        """Run one jitted op over a ``(T, B, ·)`` tile.
+
+        No launch-level batch guard: the kernels batch-tile internally
+        (tile rows from :meth:`tile_rows`) — any B runs, only a *tile* must
+        fit VMEM.  The first launch of each argument signature compiles
+        ahead of the call (the call then reuses that build: one compile
+        per program, ``tests/test_backend.py``), so a program that cannot
+        be built raises
+        :class:`CompileError` rather than surfacing as a device fault.
+        Inside a caller's trace (e.g. a jitted training step) the caller's
+        own compilation reports instead."""
         self._shapes.setdefault(op, set()).add(tuple(shape[:2]))
+        leaves, tree = jax.tree.flatten(args)
+        if not any(isinstance(x, jax.core.Tracer) for x in leaves):
+            sig = (op, tree,
+                   tuple((np.shape(x), np.result_type(x)) for x in leaves))
+            if sig not in self._built:
+                try:
+                    fn.lower(*args).compile()
+                except Exception as e:
+                    raise CompileError(
+                        f"{op} program for tile {tuple(shape)} failed to "
+                        f"build on {self.backend!r}: {type(e).__name__}: {e}"
+                    ) from e
+                self._built.add(sig)
+        return fn(*args)
 
     def tile_rows(self, op: str, T: Optional[int] = None) -> int:
         """Batch rows per kernel tile for ``op`` on this backend's config —
@@ -570,8 +602,8 @@ class ExecutionBackend:
         tile (plus per-sample spike counts) is written to HBM — no per-tick
         streams on the serving path.
         """
-        self._note("inference", raster.shape)
-        return self._jit_inference(weights, raster, valid)
+        return self._launch("inference", self._jit_inference, raster.shape,
+                            weights, raster, valid)
 
     # ------------------------------------------------------- forward traces
 
@@ -609,8 +641,8 @@ class ExecutionBackend:
         valid: jax.Array,
     ) -> Traces:
         """Forward one ``(T, B)`` tile, emitting the factored-update traces."""
-        self._note("forward_traces", raster.shape)
-        return self._jit_forward(weights, raster, y_star, valid)
+        return self._launch("forward_traces", self._jit_forward, raster.shape,
+                            weights, raster, y_star, valid)
 
     # --------------------------------------------------------- eprop update
 
@@ -633,8 +665,8 @@ class ExecutionBackend:
         self, weights: Dict[str, jax.Array], traces: Traces
     ) -> Dict[str, jax.Array]:
         """Traces → batch-summed positive-gradient ``dw`` pytree."""
-        self._note("eprop_update", traces["h"].shape)
-        return self._jit_update(weights, traces)
+        return self._launch("eprop_update", self._jit_update,
+                            traces["h"].shape, weights, traces)
 
     # ----------------------------------------------------------- train tile
 
@@ -714,7 +746,7 @@ class ExecutionBackend:
             m = dict(m, spike_rate=self._psum_spike_rate(m["spike_rate"], valid))
             return dw, m
 
-        dw, m = shard_map(
+        dw, m = jax.shard_map(
             local,
             mesh=self.mesh,
             axis_names=set(ba),
@@ -820,7 +852,7 @@ class ExecutionBackend:
             }
             return codes, m
 
-        codes, m = shard_map(
+        codes, m = jax.shard_map(
             local,
             mesh=self.mesh,
             axis_names=set(ba),
@@ -847,7 +879,7 @@ class ExecutionBackend:
                 spike_rate=self._psum_spike_rate(out["spike_rate"], valid),
             )
 
-        out = shard_map(
+        out = jax.shard_map(
             local,
             mesh=self.mesh,
             axis_names=set(ba),
@@ -878,8 +910,8 @@ class ExecutionBackend:
         sharded over the data axes and ``dw`` is ``psum``-med, so the commit
         is identical to the single-device one.
         """
-        self._note("train_tile", raster.shape)
-        return self._jit_train(weights, raster, y_star, valid)
+        return self._launch("train_tile", self._jit_train, raster.shape,
+                            weights, raster, y_star, valid)
 
     # ------------------------------------------------------------- dynamics
 
@@ -906,8 +938,8 @@ class ExecutionBackend:
         (:func:`repro.core.quant_ref.golden_forward`) exactly on these —
         asserted in ``tests/test_quant_equivalence.py``.
         """
-        self._note("dynamics", raster.shape)
-        return self._jit_dynamics(weights, raster)
+        return self._launch("dynamics", self._jit_dynamics, raster.shape,
+                            weights, raster)
 
     # -------------------------------------------------------- step sessions
 
@@ -963,7 +995,7 @@ class ExecutionBackend:
         raster, live, valid = padded[:3]
         state = dict(zip(keys, padded[3:]))
 
-        out = shard_map(
+        out = jax.shard_map(
             self._step_sessions_impl,
             mesh=self.mesh,
             axis_names=set(ba),
@@ -1001,8 +1033,8 @@ class ExecutionBackend:
         ``lax.scan``; with a mesh, session rows shard over the data axes
         (pure per-session outputs — no collectives).
         """
-        self._note("step_sessions", raster.shape)
-        return self._jit_step_sessions(weights, raster, live, valid, state)
+        return self._launch("step_sessions", self._jit_step_sessions,
+                            raster.shape, weights, raster, live, valid, state)
 
 
 BackendLike = Union[str, ExecutionBackend]

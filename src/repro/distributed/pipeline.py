@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 
 def gpipe(
@@ -68,7 +67,7 @@ def gpipe(
         mask = (s == n_stages - 1).astype(outs.dtype)
         return jax.lax.psum(outs * mask, axis)
 
-    return shard_map(
+    return jax.shard_map(
         inner,
         mesh=mesh,
         axis_names={axis},

@@ -60,17 +60,17 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.quant import QuantizedMode
 from repro.kernels.rsnn_step import (
     DEFAULT_VMEM_BUDGET,
-    PHYSICAL_VMEM_CEILING,
-    _block_bitmap,
+    _dma_operands,
     _pad_batch_axis,
     _stream_events,
     _tile_batch,
-    fused_train_bytes,
     max_forward_tile,
     max_fused_train_tile,
     tick_from_input_current,
     tick_transition,
+    vmem_laid_out_bytes,
 )
+from repro.launch.mesh import VMEM_BYTES
 
 
 def _flush_dw(b, acc_in_scr, acc_rec_scr, acc_out_scr,
@@ -202,7 +202,7 @@ def eprop_update(
 def _train_kernel(
     raster_ref,   # (1, B, N_in) — tick (i mod T)'s input spikes
     y_star_ref,   # (B, O) one-hot targets
-    valid_ref,    # (1, B) TARGET_VALID mask for tick (i mod T)
+    valid_ref,    # (1, B, 1) TARGET_VALID mask for tick (i mod T)
     w_in_ref,     # (N_in, H)
     w_rec_ref,    # (H, H)
     w_out_ref,    # (H, O)
@@ -265,7 +265,7 @@ def _train_kernel(
     def _forward():
         t = i
         x_t = raster_ref[0]
-        valid_t = valid_ref[0]                 # (B,)
+        valid_t = valid_ref[0]                 # (B, 1)
         z = z_scr[...]
 
         v_new, z_new, y_new, h = tick_transition(
@@ -286,7 +286,7 @@ def _train_kernel(
             err = jax.nn.softmax(y_err, axis=-1) - y_star_ref[...]
         else:
             err = y_err - target_amplitude * y_star_ref[...]
-        err = err * valid_t[:, None]
+        err = err * valid_t
 
         h_tr[pl.ds(t, 1)] = h[None]
         xbar_tr[pl.ds(t, 1)] = xbar[None]
@@ -301,9 +301,9 @@ def _train_kernel(
         pbar_scr[...] = pbar
         zbar_scr[...] = zbar
 
-        w_inf = 1.0 if infer_all else valid_t[:, None]
+        w_inf = 1.0 if infer_all else valid_t
         accy_scr[...] += y_new * w_inf
-        nspk_scr[...] += (z_new * valid_t[:, None]).sum(axis=1, keepdims=True)
+        nspk_scr[...] += (z_new * valid_t).sum(axis=1, keepdims=True)
 
     @pl.when(i >= T)
     def _backward():
@@ -338,7 +338,7 @@ def _train_dma_kernel(
     bitmap_ref,   # (nb·T,) int32 scalar-prefetch activity bitmap
     raster_hbm,   # (T, b_pad, N_in) — stays in HBM, streamed manually
     y_star_ref,   # (B, O) one-hot targets
-    valid_ref,    # (1, B) TARGET_VALID mask (pinned to tick T-1 in phase 2)
+    valid_ref,    # (1, B, 1) TARGET_VALID mask (pinned to tick T-1 in phase 2)
     w_in_ref,     # (N_in, H)
     w_rec_ref,    # (H, H)
     w_out_ref,    # (H, O)
@@ -436,7 +436,7 @@ def _train_dma_kernel(
     @pl.when(forward)
     def _forward():
         t = i
-        valid_t = valid_ref[0]                 # (B,)
+        valid_t = valid_ref[0]                 # (B, 1)
         z = z_scr[...]
 
         v_new, z_new, y_new, h = tick_from_input_current(
@@ -454,7 +454,7 @@ def _train_dma_kernel(
             err = jax.nn.softmax(y_err, axis=-1) - y_star_ref[...]
         else:
             err = y_err - target_amplitude * y_star_ref[...]
-        err = err * valid_t[:, None]
+        err = err * valid_t
 
         h_tr[pl.ds(t, 1)] = h[None]
         xbar_tr[pl.ds(t, 1)] = xbar[None]
@@ -468,9 +468,9 @@ def _train_dma_kernel(
         pbar_scr[...] = pbar
         zbar_scr[...] = zbar
 
-        w_inf = 1.0 if infer_all else valid_t[:, None]
+        w_inf = 1.0 if infer_all else valid_t
         accy_scr[...] += y_new * w_inf
-        nspk_scr[...] += (z_new * valid_t[:, None]).sum(axis=1, keepdims=True)
+        nspk_scr[...] += (z_new * valid_t).sum(axis=1, keepdims=True)
 
     @pl.when(jnp.logical_not(forward))
     def _backward():
@@ -551,24 +551,16 @@ def rsnn_train(
     bt, nb, b_pad = _tile_batch(
         B, batch_tile or max_fused_train_tile(T, n_in, H, O, vmem_budget)
     )
-    # A single-row tile beyond *physical* VMEM cannot compile anywhere —
-    # fail at trace time with the actionable alternative (the split
-    # forward_traces + eprop_update ops stream the traces through HBM).
-    tile_bytes = fused_train_bytes(T, bt, n_in, H, O)
-    if tile_bytes > PHYSICAL_VMEM_CEILING:
-        raise ValueError(
-            f"fused train tile (T={T}, Bt={bt}) needs {tile_bytes} bytes of "
-            f"trace scratch — beyond physical VMEM "
-            f"({PHYSICAL_VMEM_CEILING}); shorten T or run the split "
-            "forward_traces + eprop_update pipeline, which streams traces "
-            "through HBM"
-        )
     if stream not in ("blocked", "dma"):
         raise ValueError(f"unknown stream mode {stream!r}")
     # pad rows: zero raster + zero valid -> zero err, zero dw, zero acc_y
     raster = _pad_batch_axis(raster, 1, b_pad)
     y_star = _pad_batch_axis(y_star, 0, b_pad)
-    valid = _pad_batch_axis(valid, 1, b_pad)
+    valid = _pad_batch_axis(valid, 1, b_pad)[..., None]   # (T, b_pad, 1)
+    n_net = n_in
+    if stream == "dma":
+        bitmap, raster, w_in = _dma_operands(raster, w_in, bt)
+        n_in = w_in.shape[0]
 
     consts = dict(
         alpha=float(alpha),
@@ -609,9 +601,33 @@ def rsnn_train(
         pltpu.VMEM((H, H), jnp.float32),       # dw_rec acc
         pltpu.VMEM((H, O), jnp.float32),       # dw_out acc
     ]
+    # double-buffered pipeline blocks: y_star, valid, weights + b_fb in;
+    # dw, acc_y, n_spk out
+    blocks = [(bt, O), (1, bt, 1), (n_in, H), (H, H), (H, O), (H, O),
+              (n_in, H), (H, H), (H, O), (bt, O), (bt, 1)]
+    if stream == "dma":
+        scratch += [
+            pltpu.VMEM((bt, H), jnp.float32),        # input current
+            pltpu.VMEM((2, bt, n_in), jnp.float32),  # event double buffer
+        ]
+    else:
+        blocks.append((1, bt, n_in))                 # raster tick block
+    # A tile beyond VMEM cannot compile — fail at trace time with the
+    # actionable alternative (the split forward_traces + eprop_update ops
+    # stream the traces through HBM) instead of an opaque Mosaic error.
+    tile_bytes = (vmem_laid_out_bytes(*(s.shape for s in scratch))
+                  + 2 * vmem_laid_out_bytes(*blocks))
+    if tile_bytes > VMEM_BYTES:
+        raise ValueError(
+            f"fused train tile (T={T}, Bt={bt}) needs {tile_bytes} bytes of "
+            f"VMEM — beyond the core's {VMEM_BYTES}; shorten T or run the "
+            "split forward_traces + eprop_update pipeline, which streams "
+            "traces through HBM"
+        )
+    # the whole core, not Mosaic's default scoped slice of it
+    params = pltpu.CompilerParams(vmem_limit_bytes=VMEM_BYTES)
 
     if stream == "dma":
-        bitmap = _block_bitmap(raster, bt)
         kern = functools.partial(_train_dma_kernel, **consts, nb=nb, bt=bt)
         full = lambda shape: pl.BlockSpec(
             shape, lambda b, i, s_ref: tuple(0 for _ in shape)
@@ -620,13 +636,14 @@ def rsnn_train(
             num_scalar_prefetch=1,
             grid=(nb, 2 * T),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),   # raster stays in HBM
+                pl.BlockSpec(memory_space=pl.ANY),   # raster stays in HBM
                 pl.BlockSpec((bt, O), lambda b, i, s_ref: (b, 0)),
                 # valid pins to tick T-1 across phase 2: the block index is
                 # then unchanged step-to-step, so Pallas skips the re-fetch
                 # the blocked variant's (i mod T) map pays for
                 pl.BlockSpec(
-                    (1, bt), lambda b, i, s_ref: (jnp.minimum(i, T - 1), b)
+                    (1, bt, 1),
+                    lambda b, i, s_ref: (jnp.minimum(i, T - 1), b, 0),
                 ),
                 full((n_in, H)),
                 full((H, H)),
@@ -638,15 +655,11 @@ def rsnn_train(
                 pl.BlockSpec((bt, O), lambda b, i, s_ref: (b, 0)),
                 pl.BlockSpec((bt, 1), lambda b, i, s_ref: (b, 0)),
             ],
-            scratch_shapes=scratch + [
-                pltpu.VMEM((bt, H), jnp.float32),        # input current
-                pltpu.VMEM((2, bt, n_in), jnp.float32),  # event double buffer
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
+            scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((2,))],
         )
         outs = pl.pallas_call(
             kern, grid_spec=grid_spec, out_shape=out_shape,
-            interpret=interpret,
+            compiler_params=params, interpret=interpret,
         )(bitmap, raster, y_star, valid, w_in, w_rec, w_out, b_fb)
     else:
         kern = functools.partial(_train_kernel, **consts)
@@ -662,7 +675,7 @@ def rsnn_train(
             in_specs=[
                 pl.BlockSpec((1, bt, n_in), lambda b, i: (i % T, b, 0)),
                 pl.BlockSpec((bt, O), lambda b, i: (b, 0)),
-                pl.BlockSpec((1, bt), lambda b, i: (i % T, b)),
+                pl.BlockSpec((1, bt, 1), lambda b, i: (i % T, b, 0)),
                 full((n_in, H)),
                 full((H, H)),
                 full((H, O)),
@@ -675,7 +688,8 @@ def rsnn_train(
             ],
             out_shape=out_shape,
             scratch_shapes=scratch,
+            compiler_params=params,
             interpret=interpret,
         )(raster, y_star, valid, w_in, w_rec, w_out, b_fb)
     dw_in, dw_rec, dw_out, acc_y, n_spk = outs
-    return dw_in, dw_rec, dw_out, acc_y[:B], n_spk[:B]
+    return dw_in[:n_net], dw_rec, dw_out, acc_y[:B], n_spk[:B]

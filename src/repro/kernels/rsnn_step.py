@@ -62,15 +62,10 @@ from repro.core.quant import QuantizedMode
 # tests/test_fused_kernels.py::test_tile_sizing_single_source.
 # ---------------------------------------------------------------------------
 
-# Conservative slice of the ~16 MiB/core VMEM left to one kernel tile once
-# double-buffered HBM streaming and compiler temporaries are accounted for.
+# Conservative per-tile slice of the core's VMEM (physical size:
+# repro.launch.mesh.VMEM_BYTES) left to one kernel tile once double-buffered
+# HBM streaming and compiler temporaries are accounted for.
 DEFAULT_VMEM_BUDGET = 4 * 2**20
-
-# The physical per-core ceiling: a tile whose scratch exceeds this cannot
-# compile on any TPU regardless of how far the conservative budget is
-# raised — the fused train wrapper fails loudly at trace time instead of
-# surfacing an opaque compiler OOM (there is no silent fallback any more).
-PHYSICAL_VMEM_CEILING = 16 * 2**20
 
 
 def cdiv(a: int, b: int) -> int:
@@ -78,8 +73,41 @@ def cdiv(a: int, b: int) -> int:
     traffic accounting all reuse it)."""
     return -(-a // b)
 
+
+def round_up(a: int, b: int) -> int:
+    """``a`` rounded up to a whole multiple of ``b``."""
+    return cdiv(a, b) * b
+
+
+# TPU vector tiling of f32: a block's last two dims must be multiples of
+# (SUBLANES, LANES) or span the array.  Tile rows are whole sublane groups.
+SUBLANES = 8
+LANES = 128
+
+
+def _align_rows(rows: int) -> int:
+    """Round a row count down to whole sublane groups, never below one
+    group."""
+    return max(SUBLANES, rows // SUBLANES * SUBLANES)
+
+
 F32_BYTES = 4  # bytes per element; the kernels are f32 throughout
 _F32 = F32_BYTES
+
+
+def vmem_laid_out_bytes(*shapes: Tuple[int, ...]) -> int:
+    """VMEM bytes f32 arrays of these shapes occupy as Mosaic lays them out:
+    the last two dims in whole ``(SUBLANES, LANES)`` tiles, so a 3-wide
+    output trace takes 128 lanes.  The nominal byte helpers below size
+    tiles; this one tells whether a tile fits the core at all."""
+    total = 0
+    for shape in shapes:
+        *lead, rows, cols = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+        n = _F32 * round_up(rows, SUBLANES) * round_up(cols, LANES)
+        for d in lead:
+            n *= d
+        total += n
+    return total
 
 
 def weight_elems(n_in: int, n_hid: int, n_out: int) -> int:
@@ -176,9 +204,9 @@ def max_forward_tile(
     """Batch rows per tile of the batch-tiled forward/inference/update grids
     (``grid = (ceil(B / Bt), T)``), derived from the VMEM budget and capped
     by the kernel contract."""
-    return max_batch_for_dims(
+    return _align_rows(max_batch_for_dims(
         n_in, n_hid, n_out, vmem_budget, cap=KERNEL_SAMPLE_CAP
-    )
+    ))
 
 
 def max_fused_train_tile(
@@ -192,27 +220,48 @@ def max_fused_train_tile(
     (``grid = (ceil(B / Bt), 2T)``): the largest ``Bt`` whose whole-trace
     scratch (:func:`fused_train_bytes`, linear in B) fits the budget.
 
-    Clamped to ``>= 1``: the budget is a conservative slice of physical
-    VMEM, so a single-sample tile that nominally overflows it (chip-maximal
+    Rounded down to whole sublane groups and clamped to at least one
+    (:data:`SUBLANES` rows): the budget is a conservative slice of physical
+    VMEM, so a one-group tile that nominally overflows it (chip-maximal
     ``T``) still compiles in practice — there is no fallback pipeline to
     fall back to any more.  Capped by the kernel contract above.
     """
     fixed = fused_train_bytes(T, 0, n_in, n_hid, n_out)
     per_row = fused_train_bytes(T, 1, n_in, n_hid, n_out) - fixed
     b = (vmem_budget - fixed) // per_row
-    return int(max(1, min(KERNEL_SAMPLE_CAP, b)))
+    return _align_rows(int(min(KERNEL_SAMPLE_CAP, b)))
 
 
 def _tile_batch(
     B: int, tile: int
 ) -> Tuple[int, int, int]:
-    """``(Bt, num_tiles, padded_B)`` for one launch: tile rows never exceed
-    the batch, and the batch axis is zero-padded up to a whole number of
-    tiles (padding rows carry zero input and zero valid — inert by the
-    masking invariants, sliced off by the wrappers)."""
-    bt = max(1, min(tile, B))
+    """``(Bt, num_tiles, padded_B)`` for one launch: ``Bt`` is
+    ``min(tile, B)`` rounded up to whole sublane groups (:data:`SUBLANES`) —
+    Mosaic slices and blocks the batch axis only in such groups — and the
+    batch axis is zero-padded up to a whole number of tiles (padding rows
+    carry zero input and zero valid — inert by the masking invariants,
+    sliced off by the wrappers)."""
+    bt = round_up(max(1, min(tile, B)), SUBLANES)
     nb = cdiv(B, bt)
     return bt, nb, nb * bt
+
+
+def _dma_operands(
+    raster: jax.Array, w_in: jax.Array, bt: int
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """What a DMA kernel streams: ``raster`` zero-padded along the input
+    channels to whole 128-lane tiles (and ``w_in``'s rows with it) — the
+    kernels copy whole ``(Bt, N_in)`` event blocks out of HBM, and Mosaic
+    only slices an HBM ref along lanes in multiples of :data:`LANES` — plus
+    the padded raster's block activity bitmap.  Zero channels are inert:
+    they add exact zeros to the input current and keep a zero ``xbar``.
+    Returns ``(bitmap, raster, w_in)``; the kernel's input width is then
+    ``w_in.shape[0]``."""
+    pad = round_up(raster.shape[-1], LANES) - raster.shape[-1]
+    if pad:
+        raster = jnp.pad(raster, ((0, 0), (0, 0), (0, pad)))
+        w_in = jnp.pad(w_in, ((0, pad), (0, 0)))
+    return _block_bitmap(raster, bt), raster, w_in
 
 
 def _pad_batch_axis(x: jax.Array, axis: int, target: int) -> jax.Array:
@@ -604,6 +653,10 @@ def rsnn_forward(
         B, batch_tile or max_forward_tile(n_in, H, O, vmem_budget)
     )
     raster = _pad_batch_axis(raster, 1, b_pad)
+    n_net = n_in
+    if stream == "dma":
+        bitmap, raster, w_in = _dma_operands(raster, w_in, bt)
+        n_in = w_in.shape[0]
 
     consts = dict(
         alpha=float(alpha),
@@ -632,7 +685,6 @@ def rsnn_forward(
     ]
 
     if stream == "dma":
-        bitmap = _block_bitmap(raster, bt)
         kern = functools.partial(
             _forward_dma_kernel, **consts, T=T, nb=nb, bt=bt
         )
@@ -646,7 +698,7 @@ def rsnn_forward(
             num_scalar_prefetch=1,
             grid=(nb, T),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),   # raster stays in HBM
+                pl.BlockSpec(memory_space=pl.ANY),   # raster stays in HBM
                 full((n_in, H)),
                 full((H, H)),
                 full((H, O)),
@@ -691,8 +743,8 @@ def rsnn_forward(
             interpret=interpret,
         )(raster, w_in, w_rec, w_out)
     z, h, xbar, pbar, zbar, y, v = (o[:, :B] for o in outs)
-    return {"z": z, "h": h, "xbar": xbar, "pbar": pbar, "zbar": zbar, "y": y,
-            "v": v}
+    return {"z": z, "h": h, "xbar": xbar[..., :n_net], "pbar": pbar,
+            "zbar": zbar, "y": y, "v": v}
 
 
 # ---------------------------------------------------------------------------
@@ -702,7 +754,7 @@ def rsnn_forward(
 
 def _infer_kernel(
     raster_ref,   # (1, B, N_in)
-    valid_ref,    # (1, B)
+    valid_ref,    # (1, B, 1)
     w_in_ref,     # (N_in, H)
     w_rec_ref,    # (H, H)
     w_out_ref,    # (H, O)
@@ -734,7 +786,7 @@ def _infer_kernel(
         nspk_scr[...] = jnp.zeros_like(nspk_scr)
 
     x_t = raster_ref[0]
-    valid_t = valid_ref[0]                     # (B,)
+    valid_t = valid_ref[0]                     # (B, 1)
 
     v_new, z_new, y_new, _ = tick_transition(
         x_t, v_scr[...], z_scr[...], y_scr[...],
@@ -746,9 +798,9 @@ def _infer_kernel(
     z_scr[...] = z_new
     y_scr[...] = y_new
 
-    w_inf = 1.0 if infer_all else valid_t[:, None]
+    w_inf = 1.0 if infer_all else valid_t
     acc_scr[...] += y_new * w_inf
-    nspk_scr[...] += (z_new * valid_t[:, None]).sum(axis=1, keepdims=True)
+    nspk_scr[...] += (z_new * valid_t).sum(axis=1, keepdims=True)
 
     # flush this batch tile's accumulators into its (Bt, ·) output blocks
     @pl.when(t == T - 1)
@@ -760,7 +812,7 @@ def _infer_kernel(
 def _infer_dma_kernel(
     bitmap_ref,   # (nb·T,) int32 scalar-prefetch activity bitmap
     raster_hbm,   # (T, b_pad, N_in) — stays in HBM, streamed manually
-    valid_ref,    # (1, B)
+    valid_ref,    # (1, B, 1)
     w_in_ref,     # (N_in, H)
     w_rec_ref,    # (H, H)
     w_out_ref,    # (H, O)
@@ -814,7 +866,7 @@ def _infer_dma_kernel(
     def _quiet():
         cur_scr[...] = jnp.zeros_like(cur_scr)
 
-    valid_t = valid_ref[0]                     # (B,)
+    valid_t = valid_ref[0]                     # (B, 1)
     v_new, z_new, y_new, _ = tick_from_input_current(
         cur_scr[...], v_scr[...], z_scr[...], y_scr[...],
         w_rec_ref[...], w_out_ref[...],
@@ -825,9 +877,9 @@ def _infer_dma_kernel(
     z_scr[...] = z_new
     y_scr[...] = y_new
 
-    w_inf = 1.0 if infer_all else valid_t[:, None]
+    w_inf = 1.0 if infer_all else valid_t
     acc_scr[...] += y_new * w_inf
-    nspk_scr[...] += (z_new * valid_t[:, None]).sum(axis=1, keepdims=True)
+    nspk_scr[...] += (z_new * valid_t).sum(axis=1, keepdims=True)
 
     @pl.when(t == T - 1)
     def _flush():
@@ -876,7 +928,7 @@ def rsnn_infer(
         B, batch_tile or max_forward_tile(n_in, H, O, vmem_budget)
     )
     raster = _pad_batch_axis(raster, 1, b_pad)
-    valid = _pad_batch_axis(valid, 1, b_pad)
+    valid = _pad_batch_axis(valid, 1, b_pad)[..., None]   # (T, b_pad, 1)
 
     consts = dict(
         alpha=float(alpha),
@@ -900,7 +952,8 @@ def rsnn_infer(
     ]
 
     if stream == "dma":
-        bitmap = _block_bitmap(raster, bt)
+        bitmap, raster, w_in = _dma_operands(raster, w_in, bt)
+        n_in = w_in.shape[0]
         kern = functools.partial(_infer_dma_kernel, **consts, nb=nb, bt=bt)
         full = lambda shape: pl.BlockSpec(
             shape, lambda b, t, s_ref: tuple(0 for _ in shape)
@@ -909,8 +962,8 @@ def rsnn_infer(
             num_scalar_prefetch=1,
             grid=(nb, T),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),   # raster stays in HBM
-                pl.BlockSpec((1, bt), lambda b, t, s_ref: (t, b)),
+                pl.BlockSpec(memory_space=pl.ANY),   # raster stays in HBM
+                pl.BlockSpec((1, bt, 1), lambda b, t, s_ref: (t, b, 0)),
                 full((n_in, H)),
                 full((H, H)),
                 full((H, O)),
@@ -939,7 +992,7 @@ def rsnn_infer(
             grid=(nb, T),
             in_specs=[
                 pl.BlockSpec((1, bt, n_in), lambda b, t: (t, b, 0)),
-                pl.BlockSpec((1, bt), lambda b, t: (t, b)),
+                pl.BlockSpec((1, bt, 1), lambda b, t: (t, b, 0)),
                 full((n_in, H)),
                 full((H, H)),
                 full((H, O)),
@@ -962,8 +1015,8 @@ def rsnn_infer(
 
 def _session_kernel(
     raster_ref,   # (1, B, N_in)
-    live_ref,     # (1, B) — dynamics mask (0 freezes the session this tick)
-    valid_ref,    # (1, B) — readout-accumulation mask
+    live_ref,     # (1, B, 1) — dynamics mask (0 freezes the session this tick)
+    valid_ref,    # (1, B, 1) — readout-accumulation mask
     v0_ref,       # (B, H)  initial carries gathered from the session pool
     z0_ref,       # (B, H)
     y0_ref,       # (B, O)
@@ -1004,8 +1057,8 @@ def _session_kernel(
         nspk_scr[...] = nspk0_ref[...]
 
     x_t = raster_ref[0]
-    live_t = live_ref[0][:, None]              # (B, 1)
-    valid_t = valid_ref[0][:, None]
+    live_t = live_ref[0]                       # (B, 1)
+    valid_t = valid_ref[0]
 
     v_new, z_new, y_new, _ = tick_transition(
         x_t, v_scr[...], z_scr[...], y_scr[...],
@@ -1038,8 +1091,8 @@ def _session_kernel(
 def _session_dma_kernel(
     bitmap_ref,   # (nb·T,) int32 scalar-prefetch activity bitmap
     raster_hbm,   # (T, b_pad, N_in) — stays in HBM, streamed manually
-    live_ref,     # (1, B)
-    valid_ref,    # (1, B)
+    live_ref,     # (1, B, 1)
+    valid_ref,    # (1, B, 1)
     v0_ref,       # (B, H)
     z0_ref,       # (B, H)
     y0_ref,       # (B, O)
@@ -1104,8 +1157,8 @@ def _session_dma_kernel(
     def _quiet():
         cur_scr[...] = jnp.zeros_like(cur_scr)
 
-    live_t = live_ref[0][:, None]              # (B, 1)
-    valid_t = valid_ref[0][:, None]
+    live_t = live_ref[0]                       # (B, 1)
+    valid_t = valid_ref[0]
 
     v_new, z_new, y_new, _ = tick_from_input_current(
         cur_scr[...], v_scr[...], z_scr[...], y_scr[...],
@@ -1179,8 +1232,8 @@ def rsnn_step_sessions(
         B, batch_tile or max_forward_tile(n_in, H, O, vmem_budget)
     )
     raster = _pad_batch_axis(raster, 1, b_pad)
-    live = _pad_batch_axis(live, 1, b_pad)
-    valid = _pad_batch_axis(valid, 1, b_pad)
+    live = _pad_batch_axis(live, 1, b_pad)[..., None]     # (T, b_pad, 1)
+    valid = _pad_batch_axis(valid, 1, b_pad)[..., None]
     carries = [
         _pad_batch_axis(c, 0, b_pad) for c in (v0, z0, y0, acc0, nspk0)
     ]
@@ -1210,7 +1263,8 @@ def rsnn_step_sessions(
     ]
 
     if stream == "dma":
-        bitmap = _block_bitmap(raster, bt)
+        bitmap, raster, w_in = _dma_operands(raster, w_in, bt)
+        n_in = w_in.shape[0]
         kern = functools.partial(_session_dma_kernel, **consts, nb=nb, bt=bt)
         full = lambda shape: pl.BlockSpec(
             shape, lambda b, t, s_ref: tuple(0 for _ in shape)
@@ -1220,9 +1274,9 @@ def rsnn_step_sessions(
             num_scalar_prefetch=1,
             grid=(nb, T),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),   # raster stays in HBM
-                pl.BlockSpec((1, bt), lambda b, t, s_ref: (t, b)),
-                pl.BlockSpec((1, bt), lambda b, t, s_ref: (t, b)),
+                pl.BlockSpec(memory_space=pl.ANY),   # raster stays in HBM
+                pl.BlockSpec((1, bt, 1), lambda b, t, s_ref: (t, b, 0)),
+                pl.BlockSpec((1, bt, 1), lambda b, t, s_ref: (t, b, 0)),
                 row(H), row(H), row(O), row(O), row(1),
                 full((n_in, H)),
                 full((H, H)),
@@ -1250,8 +1304,8 @@ def rsnn_step_sessions(
             grid=(nb, T),
             in_specs=[
                 pl.BlockSpec((1, bt, n_in), lambda b, t: (t, b, 0)),
-                pl.BlockSpec((1, bt), lambda b, t: (t, b)),
-                pl.BlockSpec((1, bt), lambda b, t: (t, b)),
+                pl.BlockSpec((1, bt, 1), lambda b, t: (t, b, 0)),
+                pl.BlockSpec((1, bt, 1), lambda b, t: (t, b, 0)),
                 row(H), row(H), row(O), row(O), row(1),
                 full((n_in, H)),
                 full((H, H)),

@@ -53,8 +53,8 @@ density; :func:`op_table` grows dma rows when a density is passed.
 
 **Roofline helpers** close the loop from analytic bytes to wall-clock:
 :func:`device_roofline` resolves the running device's peak HBM bandwidth
-(TPU generations from ``launch/mesh.py`` constants; a coarse DDR figure as
-the CPU fallback, flagged unmeasured), and :func:`bandwidth_table` turns
+(TPU generations from ``launch/mesh.py`` constants; any other device is an
+error, never a default), and :func:`bandwidth_table` turns
 ``(bytes, seconds)`` benchmark records into achieved-GB/s versus roofline
 rows — the table ``benchmarks/bench_kernels.py`` uploads and
 ``benchmarks/roofline.py`` tunes ``Bt``/``vmem_budget`` against.
@@ -362,19 +362,15 @@ _DEVICE_ROOFLINES = {
     "TPU v6": (1.6e12, 918e12),
 }
 
-# Coarse DDR figure for hosts without an accelerator: wall-clock there is
-# interpret-mode and meaningless, so rows are flagged unmeasured and CI
-# gates on analytic byte ratios only (same policy as the serve gate).
-_CPU_FALLBACK_BW = 40e9
-
 
 def device_roofline(device=None) -> Dict[str, object]:
     """Resolve the running device's roofline constants.
 
-    Returns ``{"kind", "hbm_bw", "peak_flops", "measured"}`` —
-    ``measured=False`` means wall-clock on this device says nothing about
-    kernel bandwidth (CPU interpret mode) and achieved-vs-roofline columns
-    are recorded for trend only, never gated."""
+    Returns ``{"kind", "hbm_bw", "peak_flops"}``.  A device whose
+    ``device_kind`` has no row above (the CPU, where kernels run in
+    interpret mode and wall-clock says nothing about the chip) raises
+    :class:`ValueError` — callers on such hosts report the roofline as not
+    measured instead."""
     from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
 
     if device is None:
@@ -385,10 +381,11 @@ def device_roofline(device=None) -> Dict[str, object]:
     for prefix, consts in _DEVICE_ROOFLINES.items():
         if kind.lower().startswith(prefix.lower()):
             hbm, flops = consts or (HBM_BW, PEAK_FLOPS_BF16)
-            return {"kind": kind, "hbm_bw": hbm, "peak_flops": flops,
-                    "measured": True}
-    return {"kind": kind, "hbm_bw": _CPU_FALLBACK_BW, "peak_flops": 0.0,
-            "measured": False}
+            return {"kind": kind, "hbm_bw": hbm, "peak_flops": flops}
+    raise ValueError(
+        f"no roofline peaks for device_kind {kind!r} (known: "
+        f"{', '.join(_DEVICE_ROOFLINES)})"
+    )
 
 
 def achieved_bandwidth(bytes_moved: int, seconds: float) -> float:
@@ -405,7 +402,6 @@ def bandwidth_table(
     Each record needs ``{"op", "bytes", "seconds"}`` (extra keys pass
     through); rows gain ``achieved_gbps``, ``roofline_gbps`` and
     ``roofline_frac`` — the fraction of device peak the launch sustained.
-    On unmeasured devices (CPU interpret mode) ``roofline_frac`` is None.
     """
     roofline = roofline or device_roofline()
     peak = float(roofline["hbm_bw"])
@@ -415,7 +411,7 @@ def bandwidth_table(
         row = dict(rec)
         row["achieved_gbps"] = bw / 1e9
         row["roofline_gbps"] = peak / 1e9
-        row["roofline_frac"] = (bw / peak) if roofline["measured"] else None
+        row["roofline_frac"] = bw / peak
         out.append(row)
     return out
 

@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.distributed.sharding import current_mesh, shard
 from repro.models.layers import make_param
 
@@ -215,7 +214,7 @@ def moe_forward(p: Dict, x: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
         # check_vma=True tracks replication properly — without it shard_map
         # emits a copy-reducer all-reduce that XLA-CPU's promotion pass
         # cannot clone for the bf16 cotangents.
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             ranked,
             mesh=mesh,
             axis_names={"model"},

@@ -69,7 +69,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.backend import BackendLike, ExecutionBackend, RuntimeConfig
+from repro.core.backend import (
+    BackendLike,
+    CompileError,
+    ExecutionBackend,
+    RuntimeConfig,
+)
 from repro.core.rsnn import RSNNConfig
 from repro.kernels import traffic
 from repro.serve import batching
@@ -506,7 +511,9 @@ class BatchedEngine:
     max_tile_retries:
         Launch-fault budget: how many times faulted work is rewound and
         relaunched (through a lane restart) before the affected
-        requests/sessions are FAULTed.
+        requests/sessions are FAULTed.  A program that fails to build
+        (:class:`~repro.core.backend.CompileError`) is not a fault: it is
+        raised out of ``serve``/``pump`` on the first launch.
     fault_hook:
         Test/chaos injection point: called as ``fault_hook(model_id,
         kind)`` (``kind ∈ {"tile", "stream"}``) at the top of every launch,
@@ -1062,6 +1069,8 @@ class BatchedEngine:
         chunks = [s.take_chunk(num_ticks) for s in sessions]
         try:
             out = self._launch_chunks(lane, sessions, chunks, num_ticks)
+        except CompileError:
+            raise   # a program error, not a device fault: never retried
         except Exception:
             self._on_stream_launch_fault(lane, sessions, chunks)
             return True
@@ -1409,6 +1418,8 @@ class BatchedEngine:
             for _ in range(self._max_tile_retries + 1):
                 try:
                     pending.append(self._launch_session_tile(lane, tile))
+                except CompileError:
+                    raise   # a program error, not a device fault
                 except Exception:
                     if not self._in_restart:
                         self._restart_lane(lane)

@@ -25,7 +25,8 @@ The harness has two halves:
   and ``benchmarks/bench_chaos.py``) — spawns the worker with a kill flag,
   watches it die, then respawns it *without* kill flags until it exits
   clean; :func:`golden_run` produces the uninterrupted reference weights
-  in-process.  Bitwise comparison is the caller's one-line job.
+  in-process, :func:`golden_worker` in a worker on the drill's own
+  platform.  Bitwise comparison is the caller's one-line job.
 
 Determinism contract that makes the bitwise gate possible: batch order is
 pure in ``(seed, epoch)`` (:mod:`repro.data.pipeline`), the stochastic-
@@ -272,6 +273,23 @@ def run_chaos(
     result = json.loads(Path(out).with_suffix(".json").read_text())
     result["restarts"] = restarts
     return result
+
+
+def golden_worker(
+    ckpt_dir: str, out: str, worker_args, mesh_devices: int = 0
+) -> Dict[str, np.ndarray]:
+    """The uninterrupted reference run in a worker subprocess — the same
+    pinned platform as the drill's workers, so both sides of the bitwise
+    comparison run the same arithmetic and the caller's process never
+    touches JAX for it.  Returns the final weights as host numpy."""
+    base = ["--ckpt-dir", ckpt_dir, "--out", out, *map(str, worker_args)]
+    proc = spawn(base, mesh_devices=mesh_devices)
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"golden worker failed rc={proc.returncode}\n{proc.stdout}"
+            f"\n{proc.stderr}"
+        )
+    return load_result_weights(out)
 
 
 def load_result_weights(out: str) -> Dict[str, np.ndarray]:

@@ -10,7 +10,7 @@ Three variants (all pure functions of (params, opt_state, batch)):
                    overlap each slice's gradient collective with the next
                    slice's compute.
 * compressed     — the pod axis is lifted out of GSPMD with
-                   ``shard_map(..., auto={'data','model'})``: each pod
+                   ``jax.shard_map(..., axis_names={'pod'})``: each pod
                    computes grads on its pod-local batch (data/model axes
                    still GSPMD-managed inside), then the cross-pod mean runs
                    through int8 + error-feedback (:mod:`repro.optim.compression`)
@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.optim.adamw import AdamW
 from repro.optim.compression import compressed_psum_mean
 
@@ -122,7 +121,7 @@ def make_train_step_compressed(
             metrics.update(om)
             return params, opt_state, residual, metrics
 
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             axis_names={"pod"},   # data/model stay under GSPMD inside

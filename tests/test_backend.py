@@ -134,6 +134,35 @@ def test_inference_parity_and_auto_resolution():
         resolve_backend("mxu")
 
 
+@pytest.mark.parametrize("backend", ["scan", "kernel"])
+def test_first_launch_compiles_each_program_once(backend):
+    """A launch builds its program ahead of the call (so a failed build
+    raises CompileError) and the call reuses that build: one backend
+    compile per program on the first launch of a signature, none after."""
+    cfg = _cfg(T=13)
+    weights = _weights(jax.random.key(2), cfg)
+    raster, _, y_star, valid = _tile(jax.random.key(4), cfg, B=7)
+    jax.block_until_ready((weights, raster, y_star, valid))
+    be = ExecutionBackend(cfg, backend)
+    compiles = []
+
+    def listen(name, secs, **kwargs):
+        if name == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        counts = []
+        for _ in range(2):
+            n0 = len(compiles)
+            jax.block_until_ready(be.train_tile(weights, raster, y_star, valid))
+            jax.block_until_ready(be.inference(weights, raster, valid))
+            counts.append(len(compiles) - n0)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert counts == [2, 0]
+
+
 def test_as_backend_shares_instance_and_checks_config():
     cfg = _cfg()
     be = ExecutionBackend(cfg, "scan")

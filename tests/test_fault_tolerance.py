@@ -444,14 +444,33 @@ def _assert_bitwise(gold, out):
 
 def test_chaos_sigkill_at_commit_boundary(tmp_path):
     """Subprocess SIGKILL at a randomized commit boundary; restart resumes
-    from the survived checkpoints and ends bitwise equal to golden."""
+    from the survived checkpoints (or from nothing) and ends bitwise equal
+    to golden."""
     gold = chaos.golden_run(**GOLD_KW)
     kill_at = int(np.random.default_rng().integers(1, 4))
     out = str(tmp_path / "result")
     res = chaos.run_chaos(str(tmp_path / "ck"), out,
                           ["--kill-at-commit", kill_at], WARGS)
-    assert res["restarts"] >= 1 and res["resumed_from"] is not None
+    assert res["restarts"] >= 1
+    # at commit 1 the only checkpoint may still be in flight on the async
+    # writer: the restart then starts from nothing
+    if kill_at >= 2:
+        assert res["resumed_from"] is not None
+    assert res["resumed_from"] is None or res["resumed_from"] <= kill_at
     _assert_bitwise(gold, out)
+
+
+def test_golden_worker_matches_in_process_golden(tmp_path):
+    """The worker-hosted golden (what bench_chaos compares against, so the
+    gate's two sides share the workers' pinned platform) lands bitwise on
+    the in-process golden."""
+    gold = chaos.golden_run(**GOLD_KW)
+    got = chaos.golden_worker(
+        str(tmp_path / "ck"), str(tmp_path / "golden"), WARGS
+    )
+    assert sorted(got) == sorted(gold)
+    for k in gold:
+        np.testing.assert_array_equal(got[k], gold[k])
 
 
 def test_chaos_sigkill_mid_save_torn_tmp(tmp_path):

@@ -21,6 +21,7 @@ from repro.kernels import traffic
 from repro.kernels.rsnn_step import (
     DEFAULT_VMEM_BUDGET,
     KERNEL_SAMPLE_CAP,
+    SUBLANES,
     fused_train_bytes,
     fused_train_fits,
     max_batch_for_dims,
@@ -123,19 +124,20 @@ def test_fused_train_batch_edges(B):
 
 def test_undersized_budget_tiles_instead_of_falling_back():
     """An undersized VMEM budget no longer routes train_tile through a
-    two-kernel fallback — the fused kernel batch-tiles down (here to
-    Bt=1) and still matches both the scan oracle and the default-budget
-    single-tile launch."""
+    two-kernel fallback — the fused kernel batch-tiles down (here to the
+    one-sublane-group floor, Bt=8, so B=11 runs as two tiles) and still
+    matches both the scan oracle and the default-budget single-tile
+    launch."""
     cfg = _cfg()
     weights = _weights(jax.random.key(7), cfg)
-    raster, y_star, valid = _tile(jax.random.key(8), cfg, B=3)
+    raster, y_star, valid = _tile(jax.random.key(8), cfg, B=11)
     tiny = 4096
     assert not fused_train_fits(
-        cfg.num_ticks, 3, cfg.n_in, cfg.n_hid, cfg.n_out, tiny
+        cfg.num_ticks, 11, cfg.n_in, cfg.n_hid, cfg.n_out, tiny
     )
     assert max_fused_train_tile(
         cfg.num_ticks, cfg.n_in, cfg.n_hid, cfg.n_out, tiny
-    ) == 1
+    ) == SUBLANES
     dw_t, m_t = _assert_train_parity(
         cfg, weights, raster, y_star, valid, vmem_budget=tiny
     )

@@ -14,7 +14,8 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import aer
+from repro.core import aer, eprop
+from repro.core.backend import CompileError
 from repro.core.rsnn import Presets, init_params
 from repro.serve import (
     BatchedEngine,
@@ -326,6 +327,29 @@ def test_stream_fault_budget_quarantines_sessions():
     h2 = eng.open_session()
     h2.feed(reqs[1])
     assert h2.result().status is ServeStatus.OK
+
+
+@pytest.mark.parametrize("path", ["serve", "pump"])
+def test_program_build_error_raises_instead_of_faulting(monkeypatch, path):
+    """A launch whose program fails to lower is a deterministic program
+    error, not a device fault: ``serve``/``pump`` raise
+    :class:`CompileError` on the first launch instead of restarting the lane
+    and returning FAULT results."""
+    cfg, params, reqs = _setup(n=2, T=32)
+    eng = BatchedEngine(cfg, params, backend="scan", max_batch=4, tick_tile=8)
+
+    def refuse(*args, **kwargs):
+        raise NotImplementedError("lowering refused")
+
+    monkeypatch.setattr(eprop, "run_stream_inference", refuse)
+    with pytest.raises(CompileError, match="lowering refused"):
+        if path == "serve":
+            eng.serve(iter(reqs))
+        else:
+            eng.open_session().feed(reqs[0])
+            eng.pump(drain=True)
+    stats = eng.stream_stats(1.0)
+    assert stats.lane_restarts == 0 and stats.quarantined == 0
 
 
 def test_harvest_nan_quarantines_one_session_tile_mates_unchanged():
