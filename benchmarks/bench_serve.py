@@ -333,7 +333,6 @@ def main_multi_model(opts):
                 "p50_latency_s": s.p50_latency_s,
                 "p99_latency_s": s.p99_latency_s,
                 "compiled_shapes": s.compiled_shapes,
-                "hbm_bytes_streamed": s.hbm_bytes_streamed,
             }
             for mid, s in per.items()
         },
@@ -442,7 +441,6 @@ def main(argv=None):
         "p99_latency_s": stats.p99_latency_s,
         "mean_batch": stats.mean_batch,
         "compiled_shapes": stats.compiled_shapes,
-        "hbm_bytes_streamed": stats.hbm_bytes_streamed,
         "mismatches": mism,
     }
     if opts.out_dir:

@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import aer, eprop
 from repro.core.backend import BackendLike, ExecutionBackend, as_backend
 from repro.core.rsnn import RSNNConfig, init_params, merge_trainable, trainable
@@ -399,18 +400,19 @@ class OnlineLearner:
         its samples, per ``ctrl.commit``) — the entry the interleaved
         train-while-serve feed (:func:`repro.data.pipeline.interleave_train_serve`)
         drives."""
-        self.key, sub = jax.random.split(self.key)
-        self.weights, self.opt_state, m = self._train_fn(
-            self.weights, self.opt_state, batch, sub
-        )
-        self._commits += 1
-        if self.registry is not None and self._commits % self.publish_every == 0:
-            self.publish()
-        if self.policy is not None and self._commits % self.policy.every == 0:
-            self.save_checkpoint()
-        if self._on_commit is not None:
-            self._on_commit(self, self._commits)
-        return m
+        with obs.span("learn.commit"):
+            self.key, sub = jax.random.split(self.key)
+            self.weights, self.opt_state, m = self._train_fn(
+                self.weights, self.opt_state, batch, sub
+            )
+            self._commits += 1
+            if self.registry is not None and self._commits % self.publish_every == 0:
+                self.publish()
+            if self.policy is not None and self._commits % self.policy.every == 0:
+                self.save_checkpoint()
+            if self._on_commit is not None:
+                self._on_commit(self, self._commits)
+            return m
 
     def train_epoch(self, pipeline, epoch: int, start_batch: int = 0) -> float:
         """One training epoch; ``start_batch`` resumes mid-epoch (replay).

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import aer
 from repro.core.controller import DeviceBatch, decode_events_to_batch
 from repro.distributed.checkpoint import ReplayCursor  # noqa: F401  (re-export)
@@ -91,9 +92,10 @@ class _Base:
         self.stats = PipelineStats()
 
     def _decode(self, words: jax.Array, meta: Dict) -> DeviceBatch:
-        return decode_events_to_batch(
-            words, meta["n_in"], meta["num_ticks"], self.label_delay
-        )
+        with obs.span("data.decode"):
+            return decode_events_to_batch(
+                words, meta["n_in"], meta["num_ticks"], self.label_delay
+            )
 
 
 class ResidentPipeline(_Base):

@@ -8,8 +8,7 @@ the split two-kernel formulation and the op-specialized fused kernels.
 
 These counts are what ``benchmarks/bench_kernels.py`` reports and gates on
 for CPU CI (where the kernels run interpreted and wall-clock is
-meaningless), what the serving engine's ``hbm_bytes_streamed`` stat sums,
-and the source of the README performance table.
+meaningless), and the source of the README performance table.
 
 All streams are f32 (4 bytes/element).  Weights are counted once per tile
 (they are VMEM-resident across the whole grid).  Per-tile stream elements:

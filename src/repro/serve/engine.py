@@ -69,6 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.backend import (
     BackendLike,
     CompileError,
@@ -76,7 +77,6 @@ from repro.core.backend import (
     RuntimeConfig,
 )
 from repro.core.rsnn import RSNNConfig
-from repro.kernels import traffic
 from repro.serve import batching
 from repro.serve.guard import (
     GuardConfig,
@@ -95,6 +95,10 @@ from repro.serve.scheduler import (
     StreamPacker,
 )
 from repro.serve.session import SessionPool, SessionSnapshot, _Session
+
+
+def _p95(values: List[float]) -> Optional[float]:
+    return float(np.percentile(values, 95)) if values else None
 
 
 @dataclasses.dataclass
@@ -133,11 +137,6 @@ class ServeStats:
     p99_latency_s: float
     mean_batch: float
     compiled_shapes: int
-    # Analytic HBM bytes the served tiles streamed when the kernel backend
-    # runs (:func:`repro.kernels.traffic.infer_fused_bytes` — one (B, O)
-    # logits tile per batch instead of seven (T, B, ·) tensors); 0 on the
-    # scan backend, which runs no Pallas tile.
-    hbm_bytes_streamed: int = 0
     # Error-model counters: how many of `requests` ended non-OK (shed is
     # the subset of rejected evicted by the admission="shed" policy), and
     # how many lane restarts the window absorbed.
@@ -157,7 +156,6 @@ class ServeStats:
         wall_s: float,
         batches: int,
         shapes: int,
-        hbm_bytes: int = 0,
         shed: int = 0,
         lane_restarts: int = 0,
     ) -> "ServeStats":
@@ -178,7 +176,6 @@ class ServeStats:
             p99_latency_s=float(np.percentile(lat, 99)),
             mean_batch=(len(ok) / batches) if batches else 0.0,
             compiled_shapes=shapes,
-            hbm_bytes_streamed=hbm_bytes,
             rejected=by[ServeStatus.REJECTED],
             expired=by[ServeStatus.EXPIRED],
             quarantined=by[ServeStatus.FAULT],
@@ -233,12 +230,12 @@ class StreamStats:
                                   # throughput, not caller stall (see below)
     ticks_per_sec: float
     p50_tile_latency_s: float     # launch → harvest per tick-tile
+    p95_tile_latency_s: float
     p99_tile_latency_s: float
     mean_lanes: float             # live lanes per tile (packing efficiency)
     evictions: int
     readmissions: int
     compiled_shapes: int          # distinct step_sessions (T, B) programs
-    hbm_bytes_streamed: int = 0
     # Error-model counters (window totals).
     rejected: int = 0             # feeds refused by the guard / overload
     expired: int = 0              # sessions dropped at pack time (deadline)
@@ -251,6 +248,10 @@ class StreamStats:
     # events_per_sec/ticks_per_sec so throughput under backpressure
     # reports what the device sustained, not how long callers stalled.
     admission_wait_s: float = 0.0
+    # p95 of a session's wait in the packer's ready queue, from entering it
+    # to being packed into a tile; recorded only while repro.obs is enabled
+    # (None when nothing was recorded).
+    p95_pack_wait_s: Optional[float] = None
     # model_id → StreamStats for that model's lane; populated when the
     # engine serves more than one model, else None.
     per_model: Optional[Dict[str, "StreamStats"]] = None
@@ -274,8 +275,6 @@ class _ModelLane:
         self.max_batch = engine._max_batch or batching.max_batch_for(
             cfg, budget, num_devices=be.num_devices
         )
-        # per-kernel-tile rows, for the analytic HBM traffic accounting
-        self.tile_rows = batching.max_batch_for(cfg, budget)
         self.scheduler = BucketingScheduler(
             self.max_batch, engine.tick_granularity, clock=engine._clock,
             rid_alloc=engine._alloc_rid,
@@ -294,7 +293,7 @@ class _ModelLane:
         self.packer = StreamPacker(
             self.max_batch, tick_tile=engine._tick_tile,
             tick_granularity=engine.tick_granularity,
-            max_pending=engine._max_pending_sessions,
+            max_pending=engine._max_pending_sessions, clock=engine._clock,
         )
         # Per-lane guard: the engine-wide policy with this model's n_in
         # resolved; None when the engine was built with guard=False.
@@ -304,6 +303,7 @@ class _ModelLane:
         )
         self.zero_states: Dict[int, Dict[str, jax.Array]] = {}
         self.tile_lat: List[float] = []
+        self.pack_wait: List[float] = []
         # Dropped-work results (REJECTED/EXPIRED/FAULT) accumulated outside
         # a serve() window — drained by BatchedEngine.take_dead_results().
         self.dead: List[ServeResult] = []
@@ -329,7 +329,7 @@ class _ModelLane:
 
     def reset_counters(self) -> None:
         self.tile_lat.clear()
-        self.bytes_streamed = 0
+        self.pack_wait.clear()
         self.tiles = 0
         self.events = 0
         self.ticks = 0
@@ -351,19 +351,6 @@ class _ModelLane:
                 b_pad
             )
         return st
-
-    def account_tile_bytes(self, num_ticks: int, b_pad: int, fn) -> None:
-        """Attribute one kernel launch's analytic HBM bytes to this lane
-        (scan runs no Pallas tile, so nothing is attributed)."""
-        if self.backend.backend != "kernel":
-            return
-        cfg = self.cfg
-        ndev = self.backend.num_devices
-        shard_b = -(-b_pad // ndev)
-        self.bytes_streamed += ndev * fn(
-            num_ticks, shard_b, cfg.n_in, cfg.n_hid, cfg.n_out,
-            batch_tile=self.tile_rows,
-        )
 
 
 class SessionHandle:
@@ -709,11 +696,6 @@ class BatchedEngine:
         b_live = len(events)
         b_pad = batching.padded_batch_size(b_live, lane.max_batch)
         raster, valid = batching.pad_batch(raster, valid, b_pad)
-        # With a data mesh, every device fetches its own replicated weight
-        # set and runs its (shard-padded) slice of the batch.
-        lane.account_tile_bytes(
-            tile.num_ticks, b_pad, traffic.infer_fused_tiled_bytes
-        )
         out = lane.backend.inference(
             lane.weights, jnp.asarray(raster), jnp.asarray(valid)
         )
@@ -978,24 +960,8 @@ class BatchedEngine:
     def _feed(self, sess: _Session, events: np.ndarray) -> int:
         lane = self._lanes[sess.model_id]
         if lane.guard is not None:
-            try:
-                events = validate_events(
-                    events, lane.guard,
-                    min_tick=max(sess.max_fed_tick, 0),
-                    what=f"session {sess.sid} feed",
-                )
-            except GuardError:
-                lane.rejected += 1
-                raise
-            backlog = len(sess.sp_tick) - sess.sp_ptr
-            incoming = int(np.count_nonzero(events >> 24 == 0x03))
-            if backlog + incoming > lane.guard.max_pending_events:
-                lane.rejected += 1
-                raise QuotaExceededError(
-                    f"session {sess.sid}: {backlog} buffered + {incoming} "
-                    f"incoming spikes exceeds max_pending_events="
-                    f"{lane.guard.max_pending_events}"
-                )
+            with obs.span("serve.guard"):
+                events = self._guard_feed(lane, sess, events)
         n = sess.feed(events)
         if sess.processable() > 0:
             t0 = self._clock()
@@ -1011,6 +977,29 @@ class BatchedEngine:
                 lane.admission_wait_s += self._clock() - t0
         return n
 
+    def _guard_feed(self, lane: _ModelLane, sess: _Session, events):
+        """The feed's guard: the words' validity and the session's spike
+        quota.  Returns the validated words; a refusal counts as rejected."""
+        try:
+            events = validate_events(
+                events, lane.guard,
+                min_tick=max(sess.max_fed_tick, 0),
+                what=f"session {sess.sid} feed",
+            )
+        except GuardError:
+            lane.rejected += 1
+            raise
+        backlog = len(sess.sp_tick) - sess.sp_ptr
+        incoming = int(np.count_nonzero(events >> 24 == 0x03))
+        if backlog + incoming > lane.guard.max_pending_events:
+            lane.rejected += 1
+            raise QuotaExceededError(
+                f"session {sess.sid}: {backlog} buffered + {incoming} "
+                f"incoming spikes exceeds max_pending_events="
+                f"{lane.guard.max_pending_events}"
+            )
+        return events
+
     def _launch_chunks(self, lane: _ModelLane, sessions, chunks, num_ticks):
         """The shared streaming launch: seat sessions in the pool (one
         batched admission scatter), decode their chunks into one rectangular
@@ -1019,22 +1008,21 @@ class BatchedEngine:
         self._inject_fault(lane, "stream")
         cfg = lane.cfg
         b_pad = batching.padded_batch_size(len(sessions), lane.max_batch)
-        raster, live, valid = batching.decode_session_chunks(
-            chunks, cfg.n_in, num_ticks, cfg.label_delay, b_pad=b_pad,
-        )
-        slots, admit = lane.pool.place(sessions)
-        if admit is not None:
-            lane.pool.admit(admit)
-        idx = lane.pool.padded_slots(slots, b_pad)
-        state = lane.pool.gather(idx)
-        out = lane.backend.step_sessions(
-            lane.weights, jnp.asarray(raster), jnp.asarray(live),
-            jnp.asarray(valid), state,
-        )
-        lane.pool.scatter(idx, out)
-        lane.account_tile_bytes(
-            num_ticks, b_pad, traffic.stream_step_tiled_bytes
-        )
+        with obs.span("serve.decode"):
+            raster, live, valid = batching.decode_session_chunks(
+                chunks, cfg.n_in, num_ticks, cfg.label_delay, b_pad=b_pad,
+            )
+        with obs.span("serve.launch"):
+            slots, admit = lane.pool.place(sessions)
+            if admit is not None:
+                lane.pool.admit(admit)
+            idx = lane.pool.padded_slots(slots, b_pad)
+            state = lane.pool.gather(idx)
+            out = lane.backend.step_sessions(
+                lane.weights, jnp.asarray(raster), jnp.asarray(live),
+                jnp.asarray(valid), state,
+            )
+            lane.pool.scatter(idx, out)
         lane.tiles += 1
         lane.lanes += len(sessions)
         lane.ticks += sum(c.n_live for c in chunks)
@@ -1051,22 +1039,31 @@ class BatchedEngine:
         (device error or injected) rewinds every chosen session's chunk,
         restarts the lane, and re-queues the survivors; a session that
         faults more than ``max_tile_retries`` times in a row is
-        quarantined."""
-        nxt = lane.packer.next_tile()
-        if nxt is None:
-            return False
-        sessions, num_ticks = nxt
-        now = self._clock()
-        live = []
-        for s in sessions:
-            if s.deadline is not None and now > s.deadline:
-                self._expire_session(lane, s)
-            else:
-                live.append(s)
-        if not live:
-            return True   # handled (dropped) work — the pump made progress
-        sessions = live
-        chunks = [s.take_chunk(num_ticks) for s in sessions]
+        quarantined.
+
+        With :mod:`repro.obs` enabled, each packed session's wait in the
+        ready queue (``t_queued`` to now) is recorded."""
+        with obs.span("serve.pack"):
+            nxt = lane.packer.next_tile()
+            if nxt is None:
+                return False
+            sessions, num_ticks = nxt
+            now = self._clock()
+            live = []
+            for s in sessions:
+                if s.deadline is not None and now > s.deadline:
+                    self._expire_session(lane, s)
+                else:
+                    live.append(s)
+            if not live:
+                return True   # handled (dropped) work — the pump made progress
+            sessions = live
+            if obs.enabled():
+                lane.pack_wait.extend(
+                    now - s.t_queued for s in sessions
+                    if s.t_queued is not None
+                )
+            chunks = [s.take_chunk(num_ticks) for s in sessions]
         try:
             out = self._launch_chunks(lane, sessions, chunks, num_ticks)
         except CompileError:
@@ -1133,7 +1130,10 @@ class BatchedEngine:
         return n
 
     def _harvest_one(self) -> None:
-        p = self._stream_pending.pop(0)
+        with obs.span("serve.harvest"):
+            self._harvest_tile(self._stream_pending.pop(0))
+
+    def _harvest_tile(self, p: _PendingStreamTile) -> None:
         lane = p.lane
         try:
             acc = np.asarray(p.acc_y)   # synchronises on this tile
@@ -1249,12 +1249,12 @@ class BatchedEngine:
                 lane.ticks / busy if wall_s > 0 else float("inf")
             ),
             p50_tile_latency_s=float(np.percentile(lat, 50)),
+            p95_tile_latency_s=float(np.percentile(lat, 95)),
             p99_tile_latency_s=float(np.percentile(lat, 99)),
             mean_lanes=(lane.lanes / tiles) if tiles else 0.0,
             evictions=lane.pool.evictions,
             readmissions=lane.pool.readmissions,
             compiled_shapes=lane.backend.compiled_shapes("step_sessions"),
-            hbm_bytes_streamed=lane.bytes_streamed,
             rejected=lane.rejected,
             expired=lane.expired,
             shed=lane.shed,
@@ -1262,6 +1262,7 @@ class BatchedEngine:
             lane_restarts=lane.lane_restarts,
             saturation_storms=lane.saturation_storms,
             admission_wait_s=lane.admission_wait_s,
+            p95_pack_wait_s=_p95(lane.pack_wait),
         )
 
     def _compiled_step_shapes(self) -> int:
@@ -1301,12 +1302,12 @@ class BatchedEngine:
             events_per_sec=events / busy if wall_s > 0 else float("inf"),
             ticks_per_sec=ticks / busy if wall_s > 0 else float("inf"),
             p50_tile_latency_s=float(np.percentile(arr, 50)),
+            p95_tile_latency_s=float(np.percentile(arr, 95)),
             p99_tile_latency_s=float(np.percentile(arr, 99)),
             mean_lanes=(sum(l.lanes for l in lanes) / tiles) if tiles else 0.0,
             evictions=sum(l.pool.evictions for l in lanes),
             readmissions=sum(l.pool.readmissions for l in lanes),
             compiled_shapes=self._compiled_step_shapes(),
-            hbm_bytes_streamed=sum(l.bytes_streamed for l in lanes),
             rejected=sum(l.rejected for l in lanes),
             expired=sum(l.expired for l in lanes),
             shed=sum(l.shed for l in lanes),
@@ -1314,6 +1315,7 @@ class BatchedEngine:
             lane_restarts=sum(l.lane_restarts for l in lanes),
             saturation_storms=sum(l.saturation_storms for l in lanes),
             admission_wait_s=wait,
+            p95_pack_wait_s=_p95([w for l in lanes for w in l.pack_wait]),
             per_model=per if len(lanes) > 1 else None,
         )
 
@@ -1349,7 +1351,6 @@ class BatchedEngine:
             lane.weights, jnp.asarray(raster), jnp.asarray(live),
             jnp.asarray(valid), lane.zero_state(b_pad),
         )
-        lane.account_tile_bytes(T, b_pad, traffic.stream_step_tiled_bytes)
         lane.tiles += 1
         lane.lanes += len(bufs)
         lane.ticks += T * len(bufs)
@@ -1397,9 +1398,6 @@ class BatchedEngine:
         (falling back to the engine's ``default_deadline_s``).
         """
         t0 = self._clock()
-        bytes0 = {
-            mid: lane.bytes_streamed for mid, lane in self._lanes.items()
-        }
         restarts0 = {
             mid: lane.lane_restarts for mid, lane in self._lanes.items()
         }
@@ -1486,9 +1484,6 @@ class BatchedEngine:
         wall = self._clock() - t0
         results.sort(key=lambda r: r.rid)
 
-        def lane_bytes(lane: _ModelLane) -> int:
-            return lane.bytes_streamed - bytes0.get(lane.model_id, 0)
-
         def lane_restarts(lane: _ModelLane) -> int:
             return lane.lane_restarts - restarts0.get(lane.model_id, 0)
 
@@ -1497,7 +1492,6 @@ class BatchedEngine:
 
         stats = ServeStats.collect(
             results, wall, batches, self._compiled_step_shapes(),
-            hbm_bytes=sum(lane_bytes(l) for l in self._lanes.values()),
             shed=sum(lane_shed(l) for l in touched.values()),
             lane_restarts=sum(lane_restarts(l) for l in touched.values()),
         )
@@ -1508,7 +1502,6 @@ class BatchedEngine:
                     wall,
                     batches_by.get(mid, 0),
                     lane.backend.compiled_shapes("step_sessions"),
-                    hbm_bytes=lane_bytes(lane),
                     shed=lane_shed(lane),
                     lane_restarts=lane_restarts(lane),
                 )

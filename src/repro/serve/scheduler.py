@@ -38,6 +38,7 @@ from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.serve import batching
 from repro.serve.guard import OverloadError
 
@@ -250,6 +251,10 @@ class StreamPacker:
     own — a session is stateful, so "shedding" it is the engine's call
     (the engine pumps inline instead, accounting the stall as admission
     wait); :meth:`enqueue` just reports the overflow via its return value.
+
+    While :mod:`repro.obs` is enabled, :meth:`enqueue` stamps a session's
+    ``t_queued`` with ``clock`` as it enters the queue (``None`` otherwise),
+    from which the engine records the session's wait when it is packed.
     """
 
     def __init__(
@@ -258,6 +263,7 @@ class StreamPacker:
         tick_tile: Optional[int] = None,
         tick_granularity: int = 32,
         max_pending: Optional[int] = None,
+        clock: Callable[[], float] = time.monotonic,
     ):
         if max_batch < 1:
             raise ValueError(f"max_batch must be >= 1, got {max_batch}")
@@ -269,6 +275,7 @@ class StreamPacker:
         self.tick_tile = tick_tile
         self.tick_granularity = tick_granularity
         self.max_pending = max_pending
+        self._clock = clock
         self._queue: deque = deque()
 
     @property
@@ -286,6 +293,7 @@ class StreamPacker:
         if self.full:
             return False
         sess.queued = True
+        sess.t_queued = self._clock() if obs.enabled() else None
         self._queue.append(sess)
         return True
 

@@ -68,7 +68,7 @@ class _Session:
         "max_fed_tick", "label", "label_tick", "label_seen", "end_seen",
         "end_tick", "closed", "n_events", "t_open", "t_last", "snapshot",
         "offloaded", "queued", "gate_label", "model_id", "status",
-        "deadline", "retries",
+        "deadline", "retries", "t_queued",
     )
 
     def __init__(
@@ -105,6 +105,7 @@ class _Session:
         self.snapshot: Optional[SessionSnapshot] = None
         self.offloaded: Optional[Dict[str, np.ndarray]] = None
         self.queued = False        # True while sitting in the packer's queue
+        self.t_queued: Optional[float] = None  # entered the queue (repro.obs)
         self.status = ServeStatus.OK   # FAULT once quarantined (sticky)
         self.deadline: Optional[float] = None  # absolute; None = no deadline
         self.retries = 0           # launch-fault rewinds since last success
