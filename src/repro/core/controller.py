@@ -48,6 +48,7 @@ still-training learner without recompiling.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import signal
 from typing import Callable, Dict, Optional, Tuple
 
@@ -90,10 +91,19 @@ class ControllerConfig:
 DeviceBatch = dict
 
 
+@functools.partial(jax.jit, static_argnames=("n_in", "num_ticks", "label_delay"))
 def decode_events_to_batch(
     words: jax.Array, n_in: int, num_ticks: int, label_delay: int = 0
 ) -> DeviceBatch:
-    """AER buffer (S, L) uint32 → dense training batch (the READM+TICK path)."""
+    """AER buffer (S, L) uint32 → dense training batch (the READM+TICK path).
+
+    Jitted with the widths and the delay static: one XLA program per
+    ``(words shape and dtype, n_in, num_ticks, label_delay)``, built on the
+    first call and dispatched whole on every later one, so a training commit
+    pays neither a trace nor an op-by-op dispatch.  Duplicate spikes add 1.0
+    and clip to 1.0, exact in any order, so the compiled scatter gives the
+    same bits however XLA orders it.
+    """
     s = aer.decode_batch(words, n_in, num_ticks)
     valid = jax.vmap(
         lambda lt, et: aer.supervision_mask(lt, et, num_ticks, label_delay)

@@ -17,7 +17,11 @@
   compute).  Capacity unbounded; steady host↔device traffic — Table 2.
 
 Both yield identical decoded batches, so the controller is mode-agnostic —
-the same way the paper's AER decoder serves both SoCs.
+the same way the paper's AER decoder serves both SoCs.  The device decodes
+the uint32 words with :func:`~repro.core.controller.decode_events_to_batch`,
+one compiled program per batch shape: every full ARM batch reuses the
+program the first one built, a ragged last chunk builds a second, and X-HEEP
+mode builds one per split (:class:`PipelineStats` counts them).
 
 Replay determinism (the fault-tolerance contract, ``docs/fault_tolerance.md``):
 batch order is a pure function of ``(seed, epoch)`` — shuffles derive a
@@ -78,11 +82,20 @@ def event_density(events, n_in: Optional[int] = None,
 
 @dataclasses.dataclass
 class PipelineStats:
-    """Telemetry for the resource benchmark (Tables 1/2 analog)."""
+    """Telemetry for the resource benchmark (Tables 1/2 analog).
+
+    ``decodes`` and ``decode_programs`` give the decode's program reuse:
+    batches decoded, and distinct ``(words shape, dtype, n_in, num_ticks,
+    label_delay)`` keys among them — the decode programs this pipeline asked
+    to be built (the jit cache is per process, so a second pipeline over the
+    same shapes counts its own but compiles nothing new).
+    """
 
     h2d_bytes: int = 0        # host→device traffic issued
     resident_bytes: int = 0   # device-resident dataset footprint
     transfers: int = 0        # number of device_put calls
+    decodes: int = 0          # batches decoded
+    decode_programs: int = 0  # distinct decode programs among them
 
 
 class _Base:
@@ -90,11 +103,19 @@ class _Base:
         self.dataset = dataset
         self.label_delay = label_delay
         self.stats = PipelineStats()
+        self._decode_keys: set = set()
 
     def _decode(self, words: jax.Array, meta: Dict) -> DeviceBatch:
+        """Decode on the device through the jitted decode: one compiled
+        program per key, reused by every later batch of that shape."""
+        n_in, num_ticks = int(meta["n_in"]), int(meta["num_ticks"])
+        key = (words.shape, str(words.dtype), n_in, num_ticks, self.label_delay)
+        self._decode_keys.add(key)
+        self.stats.decodes += 1
+        self.stats.decode_programs = len(self._decode_keys)
         with obs.span("data.decode"):
             return decode_events_to_batch(
-                words, meta["n_in"], meta["num_ticks"], self.label_delay
+                words, n_in, num_ticks, self.label_delay
             )
 
 
