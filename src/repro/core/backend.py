@@ -62,6 +62,17 @@ Backends:
 
 ``backend="auto"`` resolves to ``"kernel"`` on TPU and ``"scan"`` elsewhere.
 
+Adaptive thresholds (ALIF layers, ``cfg.neuron.n_adaptive > 0``): both
+backends run ``inference`` and ``train_tile`` — the kernel backend through
+the adaptive variants of the fused kernels (launches named
+``rsnn_train_alif``), never the scan.  Every backend counts the train tiles
+it ran by the branch that traced them (:attr:`ExecutionBackend.train_tiles`:
+``rsnn_train``, ``rsnn_train_alif`` or ``scan``).  Their metrics add
+``spike_rate_pop`` (LIF, ALIF).  Paths with no ALIF form refuse such a
+configuration with :class:`repro.core.neuron.AdaptationUnsupported`:
+``forward_traces`` / ``eprop_update``, ``dynamics`` and ``step_sessions``;
+so does quantized mode.
+
 Data parallelism: construct with ``mesh=`` (e.g.
 :func:`repro.launch.mesh.make_data_mesh`) and the ``inference`` /
 ``train_tile`` hot paths shard their sample axis over the mesh's data axes
@@ -93,12 +104,14 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import eprop
+from repro.core.neuron import AdaptationUnsupported
 from repro.core.quant import QuantizedMode, QuantSpec
 from repro.core.rsnn import RSNNConfig
 from repro.distributed import sharding as shardlib
 from repro.kernels import events, ops
 from repro.kernels.rsnn_step import (
     DEFAULT_VMEM_BUDGET,
+    Adaptation,
     _pad_batch_axis,
     cdiv,
     max_forward_tile,
@@ -288,11 +301,22 @@ class ExecutionBackend:
                 )
         self.quant = quant if quant is not None else cfg.neuron.quant
         # the neuron config every scan/kernel tile actually runs against
+        # (an ALIF layer under a quantized overlay raises here)
         self._ncfg = (
             cfg.neuron
             if self.quant == cfg.neuron.quant
             else dataclasses.replace(cfg.neuron, quant=self.quant)
         )
+        # the adaptive threshold, a static property of the configuration:
+        # None keeps every LIF program exactly as it is
+        n = self._ncfg
+        self._adapt = (Adaptation(n.n_adaptive, float(n.beta), n.rho)
+                       if n.adaptive else None)
+        # train tiles run, by the branch _train_impl took when it traced
+        # them ("rsnn_train", "rsnn_train_alif" or "scan"): a program
+        # counter the benchmark reads
+        self.train_tiles: Dict[str, int] = {}
+        self._train_path: Optional[str] = None
         self.alpha = float(cfg.neuron.alpha if alpha is None else alpha)
         if self.quant is not None:
             if alpha is not None and abs(float(alpha) - self.quant.alpha) >= 1e-9:
@@ -478,7 +502,8 @@ class ExecutionBackend:
             if T is None:
                 raise ValueError("train tile rows depend on T")
             return max_fused_train_tile(
-                T, c.n_in, c.n_hid, c.n_out, self.vmem_budget
+                T, c.n_in, c.n_hid, c.n_out, self.vmem_budget,
+                adaptive=self._adapt is not None,
             )
         return max_forward_tile(c.n_in, c.n_hid, c.n_out, self.vmem_budget)
 
@@ -554,6 +579,27 @@ class ExecutionBackend:
         (padded ticks never count), so both backends report identically."""
         return eprop._spike_rate(n_spk, valid, self.cfg.n_hid)
 
+    def _kernel_metrics(self, acc_y, n_spk, valid):
+        """Metrics of a kernel launch; an ALIF kernel's ``n_spk`` is
+        ``(B, 2)`` per population and adds ``spike_rate_pop``."""
+        metrics = {
+            "acc_y": acc_y,
+            "pred": jnp.argmax(acc_y, axis=-1),
+            "spike_rate": self._spike_rate(n_spk, valid),
+        }
+        if self._adapt is not None:
+            metrics["spike_rate_pop"] = eprop.population_rates(
+                n_spk.sum(axis=0), valid, self.cfg.n_hid,
+                self._adapt.n_adaptive)
+        return metrics
+
+    def _refuse_adaptive(self, op: str) -> None:
+        if self._adapt is not None:
+            raise AdaptationUnsupported(
+                f"{op} has no ALIF form: an adaptive-threshold layer runs "
+                "train_tile and inference only"
+            )
+
     def _y_err(self, y: jax.Array) -> jax.Array:
         """Readout values as the error path sees them: normalised units in
         quantized mode (``y / threshold``), identity otherwise."""
@@ -578,13 +624,9 @@ class ExecutionBackend:
                 reset=ncfg.reset, quant=self.quant,
                 infer_window=ecfg.infer_window,
                 vmem_budget=self.vmem_budget,
-                stream=self._stream,
+                stream=self._stream, adapt=self._adapt,
             )
-            return {
-                "acc_y": acc_y,
-                "pred": jnp.argmax(acc_y, axis=-1),
-                "spike_rate": self._spike_rate(n_spk, valid),
-            }
+            return self._kernel_metrics(acc_y, n_spk, valid)
         params = self._merge(weights, raster.dtype)
         T, B = raster.shape[:2]
         return eprop.run_sample_inference(
@@ -640,7 +682,9 @@ class ExecutionBackend:
         y_star: jax.Array,
         valid: jax.Array,
     ) -> Traces:
-        """Forward one ``(T, B)`` tile, emitting the factored-update traces."""
+        """Forward one ``(T, B)`` tile, emitting the factored-update traces.
+        LIF layers only (the split path has no ALIF form)."""
+        self._refuse_adaptive("forward_traces")
         return self._launch("forward_traces", self._jit_forward, raster.shape,
                             weights, raster, y_star, valid)
 
@@ -664,7 +708,9 @@ class ExecutionBackend:
     def eprop_update(
         self, weights: Dict[str, jax.Array], traces: Traces
     ) -> Dict[str, jax.Array]:
-        """Traces → batch-summed positive-gradient ``dw`` pytree."""
+        """Traces → batch-summed positive-gradient ``dw`` pytree (LIF
+        layers only)."""
+        self._refuse_adaptive("eprop_update")
         return self._launch("eprop_update", self._jit_update,
                             traces["h"].shape, weights, traces)
 
@@ -677,6 +723,8 @@ class ExecutionBackend:
             # dw accumulated across tiles in the out refs, HBM sees only
             # dw + (B, O) metrics.  Any B runs — no fallback pipeline.
             w_in, w_rec, w_out = self._datapath_weights(weights)
+            self._train_path = ("rsnn_train" if self._adapt is None
+                                else "rsnn_train_alif")
             dw_in, dw_rec, dw_out, acc_y, n_spk = ops.rsnn_train(
                 raster, y_star, valid, w_in, w_rec, w_out,
                 self._feedback(weights),
@@ -687,15 +735,12 @@ class ExecutionBackend:
                 infer_window=ecfg.infer_window,
                 vmem_budget=self.vmem_budget,
                 stream=self._stream,
+                surrogate=ncfg.surrogate, gamma=ncfg.gamma, adapt=self._adapt,
             )
             dw = {"w_in": dw_in, "w_rec": dw_rec * self._mask,
                   "w_out": dw_out}
-            metrics = {
-                "acc_y": acc_y,
-                "pred": jnp.argmax(acc_y, axis=-1),
-                "spike_rate": self._spike_rate(n_spk, valid),
-            }
-            return dw, metrics
+            return dw, self._kernel_metrics(acc_y, n_spk, valid)
+        self._train_path = "scan"
         params = self._merge(weights, raster.dtype)
         T, B = raster.shape[:2]
         return eprop.run_sample(
@@ -726,6 +771,19 @@ class ExecutionBackend:
         den = jax.lax.psum(vs, self._batch_axes)
         return num / jnp.maximum(den, 1.0)
 
+    def _psum_rates(self, m, valid):
+        """Every rate of a shard's metrics reassembled over the mesh."""
+        m = dict(m, spike_rate=self._psum_spike_rate(m["spike_rate"], valid))
+        if "spike_rate_pop" in m:
+            m["spike_rate_pop"] = self._psum_spike_rate(m["spike_rate_pop"], valid)
+        return m
+
+    def _metric_specs(self, ba):
+        specs = {"acc_y": P(ba), "pred": P(ba), "spike_rate": P()}
+        if self._adapt is not None:
+            specs["spike_rate_pop"] = P()
+        return specs
+
     # check_vma=False below: Pallas calls have no replication rule inside
     # shard_map on current jax, and the outputs are made collective-
     # consistent explicitly (psum / per-shard slices) anyway.
@@ -743,8 +801,7 @@ class ExecutionBackend:
         def local(weights, raster, y_star, valid):
             dw, m = self._train_impl(weights, raster, y_star, valid)
             dw = jax.tree.map(lambda g: jax.lax.psum(g, ba), dw)
-            m = dict(m, spike_rate=self._psum_spike_rate(m["spike_rate"], valid))
-            return dw, m
+            return dw, self._psum_rates(m, valid)
 
         dw, m = jax.shard_map(
             local,
@@ -753,7 +810,7 @@ class ExecutionBackend:
             in_specs=(P(), P(None, ba, None), P(ba), P(None, ba)),
             out_specs=(
                 {"w_in": P(), "w_rec": P(), "w_out": P()},
-                {"acc_y": P(ba), "pred": P(ba), "spike_rate": P()},
+                self._metric_specs(ba),
             ),
             check_vma=False,
         )(weights, raster, y_star, valid)
@@ -873,18 +930,15 @@ class ExecutionBackend:
         (raster, valid), B = self._pad_to_shards((raster, valid), (1, 1))
 
         def local(weights, raster, valid):
-            out = self._inference_impl(weights, raster, valid)
-            return dict(
-                out,
-                spike_rate=self._psum_spike_rate(out["spike_rate"], valid),
-            )
+            return self._psum_rates(
+                self._inference_impl(weights, raster, valid), valid)
 
         out = jax.shard_map(
             local,
             mesh=self.mesh,
             axis_names=set(ba),
             in_specs=(P(), P(None, ba, None), P(None, ba)),
-            out_specs={"acc_y": P(ba), "pred": P(ba), "spike_rate": P()},
+            out_specs=self._metric_specs(ba),
             check_vma=False,
         )(weights, raster, valid)
         if out["acc_y"].shape[0] != B:
@@ -909,9 +963,27 @@ class ExecutionBackend:
         batch size is admitted.  With a mesh, the sample axis is first
         sharded over the data axes and ``dw`` is ``psum``-med, so the commit
         is identical to the single-device one.
+
+        An ALIF layer runs the adaptive fused kernel (kernel backend) or the
+        adaptive scan.  Each tile run counts in :attr:`train_tiles` under
+        the branch :meth:`_train_impl` took when it was traced — here for a
+        concrete call, through :meth:`count_train_tiles` for a caller that
+        traced this op into its own program (the END_B / END_S controllers).
         """
-        return self._launch("train_tile", self._jit_train, raster.shape,
-                            weights, raster, y_star, valid)
+        out = self._launch("train_tile", self._jit_train, raster.shape,
+                           weights, raster, y_star, valid)
+        if not isinstance(raster, jax.core.Tracer):
+            self.count_train_tiles(1)
+        return out
+
+    def count_train_tiles(self, n: int) -> None:
+        """Record ``n`` train tiles run by a program traced through
+        :meth:`train_tile` (called once per dispatch by the controller),
+        under the branch the trace took.  The branch is a static property
+        of the backend, so every program it traces takes the same one."""
+        if self._train_path is not None:
+            p = self._train_path
+            self.train_tiles[p] = self.train_tiles.get(p, 0) + int(n)
 
     # ------------------------------------------------------------- dynamics
 
@@ -936,8 +1008,9 @@ class ExecutionBackend:
         The hardware-equivalence probe: in quantized mode both backends
         reproduce the integer golden reference
         (:func:`repro.core.quant_ref.golden_forward`) exactly on these —
-        asserted in ``tests/test_quant_equivalence.py``.
+        asserted in ``tests/test_quant_equivalence.py``.  LIF layers only.
         """
+        self._refuse_adaptive("dynamics")
         return self._launch("dynamics", self._jit_dynamics, raster.shape,
                             weights, raster)
 
@@ -1031,8 +1104,10 @@ class ExecutionBackend:
         TARGET_VALID window of the whole-sample path.  Kernel backend runs
         the batch-tiled session kernel; scan backend the reference
         ``lax.scan``; with a mesh, session rows shard over the data axes
-        (pure per-session outputs — no collectives).
+        (pure per-session outputs — no collectives).  LIF layers only: the
+        carry has no adaptation.
         """
+        self._refuse_adaptive("step_sessions")
         return self._launch("step_sessions", self._jit_step_sessions,
                             raster.shape, weights, raster, live, valid, state)
 

@@ -154,7 +154,19 @@ def make_train_batch_fn(
             "spike_rate": rate.mean(),
         }
 
-    return train_batch
+    return _counting(train_batch, backend, lambda batch: batch["label"].shape[0])
+
+
+def _counting(train_batch, backend: ExecutionBackend, tiles):
+    """Dispatch the jitted ``train_batch`` and count the train tiles its
+    program runs (``tiles(batch)``) on the backend's counter."""
+
+    def run(weights, opt_state, batch, key):
+        out = train_batch(weights, opt_state, batch, key)
+        backend.count_train_tiles(tiles(batch))
+        return out
+
+    return run
 
 
 def batch_commit_update(
@@ -200,7 +212,8 @@ def make_batch_commit_train_fn(
     cfg: RSNNConfig, opt: EpropSGD, backend: Optional[ExecutionBackend] = None
 ):
     """Build the jit'd END_B training entry over :func:`batch_commit_update`,
-    reporting the controller's EPOCH_ACC-style counters."""
+    reporting the controller's EPOCH_ACC-style counters (an ALIF layer adds
+    its readout ``acc_y`` and per-population ``spike_rate_pop``)."""
     backend = backend or ExecutionBackend(cfg, "scan")
 
     @jax.jit
@@ -209,13 +222,17 @@ def make_batch_commit_train_fn(
             cfg, opt, backend, weights, opt_state, batch, key
         )
         correct = (metrics["pred"] == batch["label"]).astype(jnp.int32)
-        return weights, opt_state, {
+        out = {
             "correct": correct.sum(),
             "count": batch["label"].shape[0],
             "spike_rate": metrics["spike_rate"],
         }
+        if "spike_rate_pop" in metrics:      # an ALIF layer
+            out.update(acc_y=metrics["acc_y"],
+                       spike_rate_pop=metrics["spike_rate_pop"])
+        return weights, opt_state, out
 
-    return train_batch
+    return _counting(train_batch, backend, lambda batch: 1)
 
 
 def make_eval_batch_fn(cfg: RSNNConfig, backend: Optional[ExecutionBackend] = None):

@@ -31,6 +31,23 @@ Two modes:
   one SPI register drives all "alphas LSBs").
 
 Both modes share the forward LIF/LI dynamics from :mod:`repro.core.neuron`.
+
+Adaptive thresholds (ALIF, ``ncfg.n_adaptive > 0``; Bellec et al. 2020).
+With ``psi`` the surrogate at ``v - beta*a`` and ``xbar`` the presynaptic
+trace above, the eligibility gains a per-synapse adaptive component::
+
+  eps_a[t+1] = psi[t] * xbar[t] + (rho - beta * psi[t]) * eps_a[t],  eps_a[0] = 0
+  e[t]       = psi[t] * (xbar[t] - beta * eps_a[t])
+
+Exact mode carries ``eps_a`` per synapse.  Factored mode needs one more
+per-neuron reverse scan: unrolling ``eps_a`` and swapping the sums as above,
+
+  G[s] = psi[s+1] * F[s+1] + (rho - beta * psi[s+1]) * G[s+1],   G[T-1] = 0
+  dW   = xbar^T (psi ⊙ (F - beta ⊙ G))
+
+so ALIF keeps the O(T·H) form; with ``beta = 0`` it is exactly the LIF
+update.  LIF neurons are ALIF neurons with ``beta = 0``; a layer with no
+ALIF neuron runs the LIF code unchanged.
 """
 
 from __future__ import annotations
@@ -42,7 +59,15 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.neuron import NeuronConfig, lif_step, li_step, pseudo_derivative
+from repro.core.neuron import (
+    AdaptationUnsupported,
+    NeuronConfig,
+    adaptation_beta,
+    alif_step,
+    lif_step,
+    li_step,
+    pseudo_derivative,
+)
 from repro.kernels.events import sparse_input_projection
 
 
@@ -142,6 +167,31 @@ def _spike_rate(n_spk: jax.Array, valid: jax.Array, n_hid: int) -> jax.Array:
     return jnp.sum(n_spk) / (jnp.maximum(valid.sum(), 1.0) * n_hid)
 
 
+def population_counts(z: jax.Array, n_adaptive: int) -> jax.Array:
+    """Spike counts of ``z`` (``(..., H)``) per population, summed over every
+    leading axis: ``[LIF, ALIF]`` (the ALIF neurons are the last
+    ``n_adaptive``)."""
+    H = z.shape[-1]
+    return jnp.stack([z[..., : H - n_adaptive].sum(), z[..., H - n_adaptive:].sum()])
+
+
+def population_rates(counts: jax.Array, valid: jax.Array, n_hid: int,
+                     n_adaptive: int) -> jax.Array:
+    """``[LIF, ALIF]`` valid-masked spike rates from :func:`population_counts`
+    totals — each population's spikes per valid tick-neuron of its own."""
+    sizes = jnp.asarray([max(n_hid - n_adaptive, 1), max(n_adaptive, 1)],
+                        counts.dtype)
+    return counts / (jnp.maximum(valid.sum(), 1.0) * sizes)
+
+
+def _adaptive_metrics(metrics: Dict[str, jax.Array], counts: jax.Array,
+                      valid: jax.Array, n_hid: int,
+                      ncfg: NeuronConfig) -> Dict[str, jax.Array]:
+    """An ALIF layer's metrics add ``spike_rate_pop`` ``[LIF, ALIF]``."""
+    return dict(metrics, spike_rate_pop=population_rates(
+        counts, valid, n_hid, ncfg.n_adaptive))
+
+
 # ---------------------------------------------------------------------------
 # exact mode — per-synapse trace SRAM, tick-by-tick (faithful)
 # ---------------------------------------------------------------------------
@@ -160,33 +210,50 @@ def run_sample_exact(
 
     The returned ``dw`` are the *positive-gradient* sums ``sum_t L e``;
     callers apply ``w -= lr * dw`` (see :mod:`repro.optim.eprop_opt`).
+    An ALIF layer also carries the adaptation and the per-synapse ``eps_a``
+    (module doc) and adds ``spike_rate_pop`` to the metrics.
     """
     T, B, n_in = raster.shape
     H = params["w_rec"].shape[0]
     n_out = params["w_out"].shape[1]
     dtype = params["w_in"].dtype
+    adaptive = ncfg.adaptive
 
     alpha = jnp.broadcast_to(jnp.asarray(params["alpha"], dtype), (H,))
     kappa = jnp.asarray(ncfg.kappa, dtype)
     w_in_d, w_rec_d, w_out_d, rec_mask, y_scale, dot = _datapath(params, ncfg, ecfg)
     b_fb = _feedback(params, ecfg)
+    if adaptive:
+        beta = adaptation_beta(ncfg, H, dtype)
 
     in_cur = _input_projection(raster, w_in_d, dot, sparse_rows)
 
     def tick(carry, inp):
         (v, z, y, eps_in, eps_rec, ebar_in, ebar_rec, zbar,
-         dw_in, dw_rec, dw_out, acc_y, n_spk) = carry
+         dw_in, dw_rec, dw_out, acc_y, n_spk, adapt) = carry
         x_t, in_cur_t, valid_t = inp
 
         current = in_cur_t + dot(z, w_rec_d)
-        v_new, z_new, v_pre = lif_step(v, current, alpha, ncfg)
+        if adaptive:
+            a, eps_a_in, eps_a_rec = adapt
+            v_new, a_new, z_new, v_pre = alif_step(v, a, current, alpha, beta, ncfg)
+        else:
+            v_new, z_new, v_pre = lif_step(v, current, alpha, ncfg)
         y_new = li_step(y, dot(z_new, w_out_d), kappa, ncfg)
 
         h = pseudo_derivative(v_pre, ncfg)                       # (B, H)
         eps_in = alpha[None, None, :] * eps_in + x_t[:, :, None]   # (B, N_in, H)
         eps_rec = alpha[None, None, :] * eps_rec + z[:, :, None]   # (B, H, H)
-        ebar_in = kappa * ebar_in + h[:, None, :] * eps_in
-        ebar_rec = kappa * ebar_rec + h[:, None, :] * eps_rec
+        if adaptive:
+            hb = h[:, None, :]
+            ebar_in = kappa * ebar_in + hb * (eps_in - beta * eps_a_in)
+            ebar_rec = kappa * ebar_rec + hb * (eps_rec - beta * eps_a_rec)
+            decay = (ncfg.rho - beta * h)[:, None, :]
+            adapt = (a_new, hb * eps_in + decay * eps_a_in,
+                     hb * eps_rec + decay * eps_a_rec)
+        else:
+            ebar_in = kappa * ebar_in + h[:, None, :] * eps_in
+            ebar_rec = kappa * ebar_rec + h[:, None, :] * eps_rec
         zbar = kappa * zbar + z_new
 
         # y_scale is 1.0 in float mode (exact identity multiply)
@@ -199,13 +266,21 @@ def run_sample_exact(
 
         w_inf = valid_t[:, None] if ecfg.infer_window == "valid" else 1.0
         acc_y = acc_y + y_new * w_inf
-        n_spk = n_spk + (z_new * valid_t[:, None]).sum()
+        if adaptive:
+            n_spk = n_spk + population_counts(z_new * valid_t[:, None],
+                                              ncfg.n_adaptive)
+        else:
+            n_spk = n_spk + (z_new * valid_t[:, None]).sum()
 
         carry = (v_new, z_new, y_new, eps_in, eps_rec, ebar_in, ebar_rec,
-                 zbar, dw_in, dw_rec, dw_out, acc_y, n_spk)
+                 zbar, dw_in, dw_rec, dw_out, acc_y, n_spk, adapt)
         return carry, None
 
     z0 = jnp.zeros((B, H), dtype)
+    adapt0 = ()
+    if adaptive:
+        adapt0 = (jnp.zeros((B, H), dtype), jnp.zeros((B, n_in, H), dtype),
+                  jnp.zeros((B, H, H), dtype))
     carry0 = (
         jnp.zeros((B, H), dtype), z0, jnp.zeros((B, n_out), dtype),
         jnp.zeros((B, n_in, H), dtype), jnp.zeros((B, H, H), dtype),
@@ -213,10 +288,11 @@ def run_sample_exact(
         jnp.zeros((B, H), dtype),
         jnp.zeros((n_in, H), dtype), jnp.zeros((H, H), dtype),
         jnp.zeros((H, n_out), dtype),
-        jnp.zeros((B, n_out), dtype), jnp.zeros((), dtype),
+        jnp.zeros((B, n_out), dtype),
+        jnp.zeros((2,) if adaptive else (), dtype), adapt0,
     )
     carry, _ = jax.lax.scan(tick, carry0, (raster, in_cur, valid))
-    (*_, dw_in, dw_rec, dw_out, acc_y, n_spk) = carry
+    (*_, dw_in, dw_rec, dw_out, acc_y, n_spk, _) = carry
 
     dw = {"w_in": dw_in, "w_rec": dw_rec * rec_mask, "w_out": dw_out}
     metrics = {
@@ -224,6 +300,8 @@ def run_sample_exact(
         "pred": jnp.argmax(acc_y, axis=-1),
         "spike_rate": _spike_rate(n_spk, valid, H),
     }
+    if adaptive:
+        metrics = _adaptive_metrics(metrics, n_spk, valid, H, ncfg)
     return dw, metrics
 
 
@@ -241,11 +319,16 @@ def forward_traces(
     ecfg: EpropConfig,
     sparse_rows: int | None = None,
 ):
-    """Forward pass storing the O(T·H) quantities the factored update needs."""
+    """Forward pass storing the O(T·H) quantities the factored update needs.
+
+    An ALIF layer carries its adaptation, evaluates ``h`` at
+    ``v - beta*a``, and counts spikes per population (``n_spk`` is then
+    ``(T, 2)``); the traces are the same as a LIF layer's."""
     T, B, n_in = raster.shape
     H = params["w_rec"].shape[0]
     n_out = params["w_out"].shape[1]
     dtype = params["w_in"].dtype
+    adaptive = ncfg.adaptive
 
     alpha = jnp.asarray(params["alpha"], dtype)
     if alpha.ndim != 0:
@@ -254,14 +337,21 @@ def forward_traces(
         )
     kappa = jnp.asarray(ncfg.kappa, dtype)
     w_in_d, w_rec_d, w_out_d, _, y_scale, dot = _datapath(params, ncfg, ecfg)
+    if adaptive:
+        beta = adaptation_beta(ncfg, H, dtype)
 
     in_cur = _input_projection(raster, w_in_d, dot, sparse_rows)
 
     def tick(carry, inp):
-        v, z, y, xbar, pbar, zbar = carry
+        v, z, y, xbar, pbar, zbar, adapt = carry
         x_t, in_cur_t, valid_t = inp
         current = in_cur_t + dot(z, w_rec_d)
-        v_new, z_new, v_pre = lif_step(v, current, alpha, ncfg)
+        if adaptive:
+            v_new, a_new, z_new, v_pre = alif_step(
+                v, adapt[0], current, alpha, beta, ncfg)
+            adapt = (a_new,)
+        else:
+            v_new, z_new, v_pre = lif_step(v, current, alpha, ncfg)
         y_new = li_step(y, dot(z_new, w_out_d), kappa, ncfg)
         h = pseudo_derivative(v_pre, ncfg)
         xbar = alpha * xbar + x_t        # alpha-filtered input trace   (B, N_in)
@@ -269,14 +359,18 @@ def forward_traces(
         zbar = kappa * zbar + z_new      # kappa-filtered spikes        (B, H)
         err = readout_error(y_new * y_scale, y_star, ecfg) * valid_t[:, None]
         w_inf = valid_t[:, None] if ecfg.infer_window == "valid" else jnp.ones_like(valid_t)[:, None]
-        outs = (h, xbar, pbar, zbar, err, y_new * w_inf,
-                (z_new * valid_t[:, None]).sum())
-        return (v_new, z_new, y_new, xbar, pbar, zbar), outs
+        if adaptive:
+            spikes = population_counts(z_new * valid_t[:, None], ncfg.n_adaptive)
+        else:
+            spikes = (z_new * valid_t[:, None]).sum()
+        outs = (h, xbar, pbar, zbar, err, y_new * w_inf, spikes)
+        return (v_new, z_new, y_new, xbar, pbar, zbar, adapt), outs
 
     carry0 = (
         jnp.zeros((B, H), dtype), jnp.zeros((B, H), dtype),
         jnp.zeros((B, n_out), dtype), jnp.zeros((B, n_in), dtype),
         jnp.zeros((B, H), dtype), jnp.zeros((B, H), dtype),
+        (jnp.zeros((B, H), dtype),) if adaptive else (),
     )
     _, (h, xbar, pbar, zbar, err, y_inf, n_spk) = jax.lax.scan(
         tick, carry0, (raster, in_cur, valid)
@@ -294,7 +388,8 @@ def factored_update(
     ncfg: NeuronConfig,
     ecfg: EpropConfig,
 ) -> Dict[str, jax.Array]:
-    """End-of-sample update: reverse kappa-scan + three matmuls (MXU-bound)."""
+    """End-of-sample update: reverse kappa-scan + three matmuls (MXU-bound).
+    An ALIF layer adds the reverse G scan of the module doc."""
     kappa = jnp.asarray(ncfg.kappa, h.dtype)
     b_fb = _feedback(params, ecfg)
     L = jnp.einsum("tbo,ho->tbh", err, b_fb)            # learning signals
@@ -306,7 +401,21 @@ def factored_update(
 
     _, F = jax.lax.scan(rev, jnp.zeros_like(L[0]), L, reverse=True)
 
-    G = h * F                                            # (T, B, H)
+    if ncfg.adaptive:
+        beta = adaptation_beta(ncfg, h.shape[-1], h.dtype)
+
+        # G[s] = h[s+1] F[s+1] + (rho - beta h[s+1]) G[s+1], G[T-1] = 0
+        def rev_a(g, hf):
+            h_n, f_n = hf
+            g = h_n * f_n + (ncfg.rho - beta * h_n) * g
+            return g, g
+
+        nxt = lambda a: jnp.concatenate([a[1:], jnp.zeros_like(a[:1])])
+        _, Ga = jax.lax.scan(rev_a, jnp.zeros_like(L[0]), (nxt(h), nxt(F)),
+                             reverse=True)
+        G = h * (F - beta * Ga)
+    else:
+        G = h * F                                        # (T, B, H)
     dw_in = jnp.einsum("tbi,tbh->ih", xbar, G)
     dw_rec = jnp.einsum("tbk,tbh->kh", pbar, G)
     dw_out = jnp.einsum("tbh,tbo->ho", zbar, err)
@@ -331,11 +440,14 @@ def run_sample_factored(
     )
     dw = factored_update(params, h, xbar, pbar, zbar, err, ncfg, ecfg)
     acc_y = y_inf.sum(axis=0)
+    H = params["w_rec"].shape[0]
     metrics = {
         "acc_y": acc_y,
         "pred": jnp.argmax(acc_y, axis=-1),
-        "spike_rate": _spike_rate(n_spk, valid, params["w_rec"].shape[0]),
+        "spike_rate": _spike_rate(n_spk, valid, H),
     }
+    if ncfg.adaptive:
+        metrics = _adaptive_metrics(metrics, n_spk.sum(axis=0), valid, H, ncfg)
     return dw, metrics
 
 
@@ -363,31 +475,45 @@ def run_sample_inference(
     H = params["w_rec"].shape[0]
     n_out = params["w_out"].shape[1]
     dtype = params["w_in"].dtype
+    adaptive = ncfg.adaptive
     alpha = jnp.broadcast_to(jnp.asarray(params["alpha"], dtype), (H,))
     kappa = jnp.asarray(ncfg.kappa, dtype)
     w_in_d, w_rec_d, w_out_d, _, _, dot = _datapath(params, ncfg, ecfg)
+    if adaptive:
+        beta = adaptation_beta(ncfg, H, dtype)
 
     in_cur = _input_projection(raster, w_in_d, dot, sparse_rows)
 
     def tick(carry, inp):
-        v, z, y, acc_y, n_spk = carry
+        v, z, y, acc_y, n_spk, adapt = carry
         in_cur_t, valid_t = inp
         current = in_cur_t + dot(z, w_rec_d)
-        v_new, z_new, _ = lif_step(v, current, alpha, ncfg)
+        if adaptive:
+            v_new, a_new, z_new, _ = alif_step(v, adapt[0], current, alpha,
+                                               beta, ncfg)
+            adapt = (a_new,)
+            spikes = population_counts(z_new * valid_t[:, None], ncfg.n_adaptive)
+        else:
+            v_new, z_new, _ = lif_step(v, current, alpha, ncfg)
+            spikes = (z_new * valid_t[:, None]).sum()
         y_new = li_step(y, dot(z_new, w_out_d), kappa, ncfg)
         w_inf = valid_t[:, None] if ecfg.infer_window == "valid" else 1.0
         return (v_new, z_new, y_new, acc_y + y_new * w_inf,
-                n_spk + (z_new * valid_t[:, None]).sum()), None
+                n_spk + spikes, adapt), None
 
     carry0 = (jnp.zeros((B, H), dtype), jnp.zeros((B, H), dtype),
               jnp.zeros((B, n_out), dtype), jnp.zeros((B, n_out), dtype),
-              jnp.zeros((), dtype))
-    (v, z, y, acc_y, n_spk), _ = jax.lax.scan(tick, carry0, (in_cur, valid))
-    return {
+              jnp.zeros((2,) if adaptive else (), dtype),
+              (jnp.zeros((B, H), dtype),) if adaptive else ())
+    (v, z, y, acc_y, n_spk, _), _ = jax.lax.scan(tick, carry0, (in_cur, valid))
+    metrics = {
         "acc_y": acc_y,
         "pred": jnp.argmax(acc_y, axis=-1),
         "spike_rate": _spike_rate(n_spk, valid, H),
     }
+    if adaptive:
+        metrics = _adaptive_metrics(metrics, n_spk, valid, H, ncfg)
+    return metrics
 
 
 def run_stream_inference(
@@ -419,6 +545,11 @@ def run_stream_inference(
     backend (asserted in ``tests/test_streaming.py``, bit-true against the
     integer golden reference in quantized mode).
     """
+    if ncfg.adaptive:
+        raise AdaptationUnsupported(
+            "streaming sessions carry (v, z, y, acc_y, n_spk) and no "
+            "adaptation: an ALIF layer serves whole samples only"
+        )
     T, B, n_in = raster.shape
     H = params["w_rec"].shape[0]
     dtype = params["w_in"].dtype
@@ -468,7 +599,12 @@ def forward_dynamics(
     Returns ``{"v": post-reset membrane (T, B, H), "v_pre": pre-reset
     membrane, "z": spikes, "y": readout (T, B, O)}``.  In quantized mode
     every value is an integer on the membrane grid (carried in float32).
+    LIF layers only.
     """
+    if ncfg.adaptive:
+        raise AdaptationUnsupported(
+            "the dynamics probe has no ALIF form (no adaptation trajectory)"
+        )
     T, B, n_in = raster.shape
     H = params["w_rec"].shape[0]
     n_out = params["w_out"].shape[1]

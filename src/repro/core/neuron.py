@@ -12,12 +12,23 @@ used in the paper:
 
 The pseudo-derivative used for the eligibility traces is a hardware-friendly
 boxcar window (1 inside ``|v - vth| < width``, 0 outside), with Bellec's
-triangular surrogate also available for the BPTT cross-checks in the tests.
+triangular surrogate also available (the LSNN configuration trains with it).
+
+Adaptive threshold (ALIF, the LSNN of Bellec et al., Nat. Comm. 2020): the
+last ``n_adaptive`` neurons of the layer carry one more state, the
+adaptation ``a``, and spike against ``A = v_th + beta * a``::
+
+    z = H(v - beta * a - v_th),   a <- rho * a + z,   rho = exp(-1 / tau_a)
+
+The membrane still resets by ``v_th`` and the surrogate is evaluated at
+``v - beta * a`` (i.e. at ``v - A`` relative to ``v_th``).  ReckOn's
+datapath has no adaptive threshold, so adaptation is float-only.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Optional, Tuple
 
@@ -25,6 +36,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.quant import QuantizedMode
+
+
+class AdaptationUnsupported(ValueError):
+    """An adaptive-threshold (ALIF) configuration reached a path that has
+    no ALIF form: quantized mode, streaming sessions, or the split
+    ``forward_traces`` / ``eprop_update`` pair."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,11 +59,49 @@ class NeuronConfig:
     # by the raw threshold register.  Membranes, currents and weights are
     # then integer values carried in float32 (see repro.core.quant).
     quant: Optional[QuantizedMode] = None
+    # Adaptive threshold: the last n_adaptive neurons of the layer are ALIF
+    # neurons with threshold increment beta per unit of adaptation, which
+    # decays with time constant tau_a ticks.  0 = a pure LIF layer.
+    n_adaptive: int = 0
+    beta: float = 0.0
+    tau_a: float = 0.0
+
+    def __post_init__(self):
+        if self.n_adaptive < 0:
+            raise ValueError(f"n_adaptive must be >= 0, got {self.n_adaptive}")
+        if self.n_adaptive:
+            if self.quant is not None:
+                raise AdaptationUnsupported(
+                    "ReckOn's fixed-point datapath has no adaptive threshold: "
+                    "an ALIF layer (n_adaptive > 0) runs in float mode only"
+                )
+            if self.tau_a <= 0:
+                raise ValueError(
+                    f"an ALIF layer needs tau_a > 0 ticks, got {self.tau_a}"
+                )
+
+    @property
+    def adaptive(self) -> bool:
+        """Whether the layer has ALIF neurons — a static property of the
+        configuration: every program a LIF layer compiles is unchanged."""
+        return self.n_adaptive > 0
+
+    @property
+    def rho(self) -> float:
+        """Per-tick decay of the adaptation, ``exp(-1 / tau_a)``."""
+        return math.exp(-1.0 / self.tau_a)
 
     def effective_v_th(self) -> float:
         """The spiking threshold the datapath compares against: the raw
         membrane-grid register in quantized mode, ``v_th`` otherwise."""
         return float(self.quant.threshold) if self.quant is not None else self.v_th
+
+
+def adaptation_beta(cfg: NeuronConfig, n_hid: int, dtype=jnp.float32) -> jax.Array:
+    """Per-neuron threshold increments ``(H,)``: 0 for the LIF neurons,
+    ``cfg.beta`` for the last ``cfg.n_adaptive`` (the ALIF neurons)."""
+    alif = jnp.arange(n_hid) >= n_hid - cfg.n_adaptive
+    return jnp.where(alif, cfg.beta, 0.0).astype(dtype)
 
 
 def pseudo_derivative(v_pre: jax.Array, cfg: NeuronConfig) -> jax.Array:
@@ -123,6 +178,34 @@ def lif_step(
     else:
         raise ValueError(f"unknown reset mode {cfg.reset!r}")
     return v_new, z, v_pre
+
+
+def alif_step(
+    v: jax.Array,
+    a: jax.Array,
+    current: jax.Array,
+    alpha: jax.Array,
+    beta: jax.Array,
+    cfg: NeuronConfig,
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """One tick of a layer with adaptive thresholds (float mode).
+
+    ``beta`` is the per-neuron increment of :func:`adaptation_beta` (0 on
+    LIF neurons, whose step is then exactly :func:`lif_step`'s).  Returns
+    ``(v_new, a_new, z_new, v_eff)`` with ``v_eff = v_pre - beta * a`` the
+    value the spike test and the surrogate read: ``z = v_eff >= v_th``.
+    The membrane resets by ``v_th`` (not by ``A``).
+    """
+    v_pre = alpha * v + current
+    v_eff = v_pre - beta * a
+    z = (v_eff >= cfg.v_th).astype(v.dtype)
+    if cfg.reset == "sub":
+        v_new = v_pre - z * cfg.v_th
+    elif cfg.reset == "zero":
+        v_new = v_pre * (1.0 - z)
+    else:
+        raise ValueError(f"unknown reset mode {cfg.reset!r}")
+    return v_new, cfg.rho * a + z, z, v_eff
 
 
 def lif_step_surrogate(

@@ -14,6 +14,7 @@ default is faithful.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict
 
 import jax
@@ -58,6 +59,11 @@ class RSNNConfig:
                     )
         if self.num_ticks > 4096:
             raise ValueError("tick counter is 12-bit on the AER bus")
+        if self.neuron.n_adaptive > self.n_hid:
+            raise ValueError(
+                f"{self.neuron.n_adaptive} adaptive neurons > {self.n_hid} "
+                "recurrent neurons"
+            )
 
 
 def init_params(key: jax.Array, cfg: RSNNConfig) -> Dict[str, jax.Array]:
@@ -113,7 +119,43 @@ def sram_bytes(cfg: RSNNConfig, weight_bits: int = 8) -> int:
 
 @dataclasses.dataclass(frozen=True)
 class Presets:
-    """The two experimental networks of the paper."""
+    """The two experimental networks of the paper, and the LSNN of e-prop's
+    evidence-accumulation task."""
+
+    @staticmethod
+    def lsnn_evidence(num_ticks: int = 2250, **over) -> RSNNConfig:
+        """Bellec et al. 2020 (e-prop), evidence accumulation: 40 inputs, one
+        recurrent layer of 50 LIF + 50 ALIF neurons, 2 softmax outputs with
+        the error given only in the recall window.  Float mode (ReckOn's
+        datapath has no adaptive threshold), 1 ms ticks.
+
+        tau_m = 20 ms (alpha = e^(-1/20)), readout tau = 20 ms (kappa),
+        v_th = 0.6, tau_a = 2,000 ms, beta = 1.8, reset by v_th, Bellec's
+        triangular surrogate with gamma 0.3.  The sample length 2,250 is the
+        cue trial of ``configs/lsnn_evidence.py``: 7 cues of 100 ticks with
+        gaps of 50, a delay of 1,050 and a recall of 150.
+        """
+        decay = math.exp(-1.0 / 20.0)
+        kw = dict(
+            n_in=40,
+            n_hid=100,
+            n_out=2,
+            num_ticks=num_ticks,
+            neuron=NeuronConfig(
+                alpha=decay,
+                kappa=decay,
+                v_th=0.6,
+                reset="sub",
+                surrogate="triangular",
+                gamma=0.3,
+                n_adaptive=50,
+                beta=1.8,
+                tau_a=2000.0,
+            ),
+            eprop=EpropConfig(mode="factored", error="softmax", infer_window="valid"),
+        )
+        kw.update(over)
+        return RSNNConfig(**kw)
 
     @staticmethod
     def cue_accumulation(

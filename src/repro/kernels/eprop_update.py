@@ -37,6 +37,18 @@ The reverse pass computes, over ticks T-1..0,
 i.e. the per-synapse eligibility SRAM of the chip becomes three VMEM-resident
 accumulator tiles fed by per-tick rank-B matmul updates.
 
+An ALIF layer (static ``adapt``, :class:`repro.kernels.rsnn_step.Adaptation`)
+runs its own variant of the fused kernel: the forward carries the adaptation
+``a`` and spikes against ``v - beta*a``; the reverse pass adds the per-neuron
+filter of :mod:`repro.core.eprop`,
+
+  G[t]   = h[t+1]·F[t+1] + (ρ - β·h[t+1])·G[t+1]    (VMEM-carried, G[T-1] = 0)
+  dW_in  = Σ_t xbar[t]ᵀ (h[t]∘(F[t] - β∘G[t]))       (and dW_rec with pbar)
+
+so it needs three more ``(Bt, H)`` carries and no more per-tick traces.  Its
+launches are named ``rsnn_train_alif`` so a device trace tells them from the
+LIF ones.
+
 Hardware-equivalence (quantized) mode needs no variant of the reverse pass:
 the chip's trace arithmetic is wider than its commit grid, so the quantized
 contract keeps e-prop traces float — the forward phase produces the same
@@ -60,10 +72,15 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.quant import QuantizedMode
 from repro.kernels.rsnn_step import (
     DEFAULT_VMEM_BUDGET,
+    Adaptation,
+    _adaptation_in,
+    _adaptation_out,
+    _count_spikes,
     _dma_operands,
     _pad_batch_axis,
     _stream_events,
     _tile_batch,
+    count_columns,
     max_forward_tile,
     max_fused_train_tile,
     tick_from_input_current,
@@ -91,6 +108,22 @@ def _flush_dw(b, acc_in_scr, acc_rec_scr, acc_out_scr,
         dw_in_ref[...] += acc_in_scr[...]
         dw_rec_ref[...] += acc_rec_scr[...]
         dw_out_ref[...] += acc_out_scr[...]
+
+
+def _reverse_signal(adapt: Optional[Adaptation], adapt_scr, h_t, F, f_next):
+    """The factor the reverse pass contracts against the presynaptic traces
+    at tick t: ``h[t]·F[t]`` for a LIF layer; for an ALIF one
+    ``h[t]·(F[t] - β·G[t])`` with ``G[t] = h[t+1]·F[t+1] +
+    (ρ - β·h[t+1])·G[t+1]`` carried in VMEM (``f_next`` is ``F[t+1]``)."""
+    if adapt is None:
+        return h_t * F
+    _, g_scr, hn_scr = adapt_scr
+    beta = adapt.beta_row(F.shape[1])
+    h_next = hn_scr[...]
+    g = h_next * f_next + (adapt.rho - beta * h_next) * g_scr[...]
+    g_scr[...] = g
+    hn_scr[...] = h_t
+    return h_t * (F - beta * g)
 
 
 def _kernel(
@@ -229,7 +262,7 @@ def _train_kernel(
     acc_in_scr,   # VMEM (N_in, H)
     acc_rec_scr,  # VMEM (H, H)
     acc_out_scr,  # VMEM (H, O)
-    *,
+    *adapt_scr,   # VMEM (B, H) ×3 — a, G carry, h[t+1] — with adapt only
     alpha: float,
     kappa: float,
     v_th: float,
@@ -241,6 +274,9 @@ def _train_kernel(
     target_amplitude: float,
     infer_all: bool,
     T: int,
+    surrogate: str = "boxcar",
+    gamma: float = 0.3,
+    adapt: Optional[Adaptation] = None,
 ):
     b = pl.program_id(0)   # batch tile
     i = pl.program_id(1)   # 0..2T-1: forward ticks 0..T-1, then T-1..0
@@ -260,6 +296,8 @@ def _train_kernel(
         acc_in_scr[...] = jnp.zeros_like(acc_in_scr)
         acc_rec_scr[...] = jnp.zeros_like(acc_rec_scr)
         acc_out_scr[...] = jnp.zeros_like(acc_out_scr)
+        for r in adapt_scr:
+            r[...] = jnp.zeros_like(r)
 
     @pl.when(i < T)
     def _forward():
@@ -267,12 +305,14 @@ def _train_kernel(
         x_t = raster_ref[0]
         valid_t = valid_ref[0]                 # (B, 1)
         z = z_scr[...]
+        a, v_shift = _adaptation_in(adapt, adapt_scr, w_rec_ref.shape[0])
 
         v_new, z_new, y_new, h = tick_transition(
             x_t, v_scr[...], z, y_scr[...],
             w_in_ref[...], w_rec_ref[...], w_out_ref[...],
             alpha=alpha, kappa=kappa, v_th=v_th, reset_sub=reset_sub,
-            boxcar_width=boxcar_width, quant=quant,
+            boxcar_width=boxcar_width, quant=quant, surrogate=surrogate,
+            gamma=gamma, v_shift=v_shift,
         )
         xbar = alpha * xbar_scr[...] + x_t
         pbar = alpha * pbar_scr[...] + z       # presyn trace: z BEFORE this tick
@@ -300,18 +340,20 @@ def _train_kernel(
         xbar_scr[...] = xbar
         pbar_scr[...] = pbar
         zbar_scr[...] = zbar
+        _adaptation_out(adapt, adapt_scr, a, z_new)
 
         w_inf = 1.0 if infer_all else valid_t
         accy_scr[...] += y_new * w_inf
-        nspk_scr[...] += (z_new * valid_t).sum(axis=1, keepdims=True)
+        nspk_scr[...] += _count_spikes(z_new * valid_t, adapt)
 
     @pl.when(i >= T)
     def _backward():
         t = 2 * T - 1 - i
         err = err_tr[pl.ds(t, 1)][0]
         L = jnp.dot(err, b_fb_ref[...].T, preferred_element_type=jnp.float32)
-        F = L + kappa * f_scr[...]
-        G = h_tr[pl.ds(t, 1)][0] * F
+        f_next = f_scr[...]
+        F = L + kappa * f_next
+        G = _reverse_signal(adapt, adapt_scr, h_tr[pl.ds(t, 1)][0], F, f_next)
 
         acc_in_scr[...] += jnp.dot(
             xbar_tr[pl.ds(t, 1)][0].T, G, preferred_element_type=jnp.float32
@@ -368,7 +410,7 @@ def _train_dma_kernel(
     cur_scr,      # VMEM (B, H) — this tick's input current (zeros if quiet)
     ev_scr,       # VMEM (2, B, N_in) — the double buffer
     sem,          # DMA semaphores (2,)
-    *,
+    *adapt_scr,   # VMEM (B, H) ×3 — a, G carry, h[t+1] — with adapt only
     alpha: float,
     kappa: float,
     v_th: float,
@@ -382,6 +424,9 @@ def _train_dma_kernel(
     T: int,
     nb: int,
     bt: int,
+    surrogate: str = "boxcar",
+    gamma: float = 0.3,
+    adapt: Optional[Adaptation] = None,
 ):
     """:func:`_train_kernel` with double-buffered event streaming.  The
     raster never enters the block pipeline: each active forward tick's
@@ -411,6 +456,8 @@ def _train_dma_kernel(
         acc_in_scr[...] = jnp.zeros_like(acc_in_scr)
         acc_rec_scr[...] = jnp.zeros_like(acc_rec_scr)
         acc_out_scr[...] = jnp.zeros_like(acc_out_scr)
+        for r in adapt_scr:
+            r[...] = jnp.zeros_like(r)
 
     active, slot = _stream_events(
         bitmap_ref, raster_hbm, ev_scr, sem,
@@ -438,12 +485,14 @@ def _train_dma_kernel(
         t = i
         valid_t = valid_ref[0]                 # (B, 1)
         z = z_scr[...]
+        a, v_shift = _adaptation_in(adapt, adapt_scr, w_rec_ref.shape[0])
 
         v_new, z_new, y_new, h = tick_from_input_current(
             cur_scr[...], v_scr[...], z, y_scr[...],
             w_rec_ref[...], w_out_ref[...],
             alpha=alpha, kappa=kappa, v_th=v_th, reset_sub=reset_sub,
-            boxcar_width=boxcar_width, quant=quant,
+            boxcar_width=boxcar_width, quant=quant, surrogate=surrogate,
+            gamma=gamma, v_shift=v_shift,
         )
         xbar = xbar_scr[...]                   # updated by the streaming step
         pbar = alpha * pbar_scr[...] + z       # presyn trace: z BEFORE this tick
@@ -467,18 +516,20 @@ def _train_dma_kernel(
         y_scr[...] = y_new
         pbar_scr[...] = pbar
         zbar_scr[...] = zbar
+        _adaptation_out(adapt, adapt_scr, a, z_new)
 
         w_inf = 1.0 if infer_all else valid_t
         accy_scr[...] += y_new * w_inf
-        nspk_scr[...] += (z_new * valid_t).sum(axis=1, keepdims=True)
+        nspk_scr[...] += _count_spikes(z_new * valid_t, adapt)
 
     @pl.when(jnp.logical_not(forward))
     def _backward():
         t = 2 * T - 1 - i
         err = err_tr[pl.ds(t, 1)][0]
         L = jnp.dot(err, b_fb_ref[...].T, preferred_element_type=jnp.float32)
-        F = L + kappa * f_scr[...]
-        G = h_tr[pl.ds(t, 1)][0] * F
+        f_next = f_scr[...]
+        F = L + kappa * f_next
+        G = _reverse_signal(adapt, adapt_scr, h_tr[pl.ds(t, 1)][0], F, f_next)
 
         acc_in_scr[...] += jnp.dot(
             xbar_tr[pl.ds(t, 1)][0].T, G, preferred_element_type=jnp.float32
@@ -521,6 +572,9 @@ def rsnn_train(
     batch_tile: Optional[int] = None,
     stream: str = "blocked",
     interpret: bool = False,
+    surrogate: str = "boxcar",
+    gamma: float = 0.3,
+    adapt: Optional[Adaptation] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """Fused forward + factored e-prop update over one ``(T, B)`` launch.
 
@@ -540,6 +594,11 @@ def rsnn_train(
     pass weights through ``QuantizedMode.to_membrane`` but ``b_fb`` in
     normalised weight units — the error is evaluated on ``y / threshold``
     in-kernel so the learning signal matches the float model's scale.
+
+    ``adapt`` (an ALIF layer, float only) selects the adaptive variant
+    (module doc), named ``rsnn_train_alif``; its ``n_spk`` is ``(B, 2)``,
+    LIF and ALIF spikes.  ``surrogate``/``gamma`` pick the pseudo-derivative
+    (boxcar or Bellec's triangle), as :mod:`repro.core.neuron` does.
     """
     T, B, n_in = raster.shape
     H = w_rec.shape[0]
@@ -549,10 +608,13 @@ def rsnn_train(
         alpha, kappa, v_th = quant.alpha, quant.kappa, float(quant.threshold)
     y_scale = 1.0 if quant is None else 1.0 / float(quant.threshold)
     bt, nb, b_pad = _tile_batch(
-        B, batch_tile or max_fused_train_tile(T, n_in, H, O, vmem_budget)
+        B, batch_tile or max_fused_train_tile(T, n_in, H, O, vmem_budget,
+                                              adaptive=adapt is not None)
     )
     if stream not in ("blocked", "dma"):
         raise ValueError(f"unknown stream mode {stream!r}")
+    if adapt is not None and quant is not None:
+        raise ValueError("adaptive thresholds are float-only")
     # pad rows: zero raster + zero valid -> zero err, zero dw, zero acc_y
     raster = _pad_batch_axis(raster, 1, b_pad)
     y_star = _pad_batch_axis(y_star, 0, b_pad)
@@ -574,13 +636,21 @@ def rsnn_train(
         target_amplitude=float(target_amplitude),
         infer_all=(infer_window == "all"),
         T=T,
+        surrogate=surrogate,
+        gamma=float(gamma),
     )
+    nc = count_columns(adapt)
+    adapt_scratch = []
+    if adapt is not None:
+        consts["adapt"] = adapt
+        # a, the reverse G carry, and h[t+1] for the G recursion
+        adapt_scratch = [pltpu.VMEM((bt, H), jnp.float32)] * 3
     out_shape = [
         jax.ShapeDtypeStruct((n_in, H), jnp.float32),
         jax.ShapeDtypeStruct((H, H), jnp.float32),
         jax.ShapeDtypeStruct((H, O), jnp.float32),
         jax.ShapeDtypeStruct((b_pad, O), dt),
-        jax.ShapeDtypeStruct((b_pad, 1), dt),
+        jax.ShapeDtypeStruct((b_pad, nc), dt),
     ]
     scratch = [
         pltpu.VMEM((bt, H), jnp.float32),      # v
@@ -590,7 +660,7 @@ def rsnn_train(
         pltpu.VMEM((bt, H), jnp.float32),      # pbar carry
         pltpu.VMEM((bt, H), jnp.float32),      # zbar carry
         pltpu.VMEM((bt, O), jnp.float32),      # acc_y
-        pltpu.VMEM((bt, 1), jnp.float32),      # n_spk
+        pltpu.VMEM((bt, nc), jnp.float32),     # n_spk
         pltpu.VMEM((T, bt, H), jnp.float32),   # h trace
         pltpu.VMEM((T, bt, n_in), jnp.float32),  # xbar trace
         pltpu.VMEM((T, bt, H), jnp.float32),   # pbar trace
@@ -604,7 +674,7 @@ def rsnn_train(
     # double-buffered pipeline blocks: y_star, valid, weights + b_fb in;
     # dw, acc_y, n_spk out
     blocks = [(bt, O), (1, bt, 1), (n_in, H), (H, H), (H, O), (H, O),
-              (n_in, H), (H, H), (H, O), (bt, O), (bt, 1)]
+              (n_in, H), (H, H), (H, O), (bt, O), (bt, nc)]
     if stream == "dma":
         scratch += [
             pltpu.VMEM((bt, H), jnp.float32),        # input current
@@ -615,7 +685,7 @@ def rsnn_train(
     # A tile beyond VMEM cannot compile — fail at trace time with the
     # actionable alternative (the split forward_traces + eprop_update ops
     # stream the traces through HBM) instead of an opaque Mosaic error.
-    tile_bytes = (vmem_laid_out_bytes(*(s.shape for s in scratch))
+    tile_bytes = (vmem_laid_out_bytes(*(s.shape for s in scratch + adapt_scratch))
                   + 2 * vmem_laid_out_bytes(*blocks))
     if tile_bytes > VMEM_BYTES:
         raise ValueError(
@@ -626,6 +696,8 @@ def rsnn_train(
         )
     # the whole core, not Mosaic's default scoped slice of it
     params = pltpu.CompilerParams(vmem_limit_bytes=VMEM_BYTES)
+    # the ALIF variant's own name in a device trace
+    name = {} if adapt is None else {"name": "rsnn_train_alif"}
 
     if stream == "dma":
         kern = functools.partial(_train_dma_kernel, **consts, nb=nb, bt=bt)
@@ -653,13 +725,14 @@ def rsnn_train(
             out_specs=[
                 full((n_in, H)), full((H, H)), full((H, O)),
                 pl.BlockSpec((bt, O), lambda b, i, s_ref: (b, 0)),
-                pl.BlockSpec((bt, 1), lambda b, i, s_ref: (b, 0)),
+                pl.BlockSpec((bt, nc), lambda b, i, s_ref: (b, 0)),
             ],
-            scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((2,))],
+            scratch_shapes=(scratch + [pltpu.SemaphoreType.DMA((2,))]
+                            + adapt_scratch),
         )
         outs = pl.pallas_call(
             kern, grid_spec=grid_spec, out_shape=out_shape,
-            compiler_params=params, interpret=interpret,
+            compiler_params=params, interpret=interpret, **name,
         )(bitmap, raster, y_star, valid, w_in, w_rec, w_out, b_fb)
     else:
         kern = functools.partial(_train_kernel, **consts)
@@ -684,12 +757,13 @@ def rsnn_train(
             out_specs=[
                 full((n_in, H)), full((H, H)), full((H, O)),
                 pl.BlockSpec((bt, O), lambda b, i: (b, 0)),
-                pl.BlockSpec((bt, 1), lambda b, i: (b, 0)),
+                pl.BlockSpec((bt, nc), lambda b, i: (b, 0)),
             ],
             out_shape=out_shape,
-            scratch_shapes=scratch,
+            scratch_shapes=scratch + adapt_scratch,
             compiler_params=params,
             interpret=interpret,
+            **name,
         )(raster, y_star, valid, w_in, w_rec, w_out, b_fb)
     dw_in, dw_rec, dw_out, acc_y, n_spk = outs
     return dw_in[:n_net], dw_rec, dw_out, acc_y[:B], n_spk[:B]

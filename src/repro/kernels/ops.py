@@ -54,7 +54,7 @@ def rsnn_forward(
 @partial(
     jax.jit,
     static_argnames=("alpha", "kappa", "v_th", "reset", "quant", "infer_window",
-                     "vmem_budget", "batch_tile", "stream"),
+                     "vmem_budget", "batch_tile", "stream", "adapt"),
 )
 def rsnn_infer(
     raster: jax.Array,
@@ -72,16 +72,19 @@ def rsnn_infer(
     vmem_budget: int = _rsnn.DEFAULT_VMEM_BUDGET,
     batch_tile: Optional[int] = None,
     stream: str = "blocked",
+    adapt: Optional[_rsnn.Adaptation] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Inference-specialized forward (serving path): batch-tiled grid,
     VMEM-accumulated ``(acc_y, n_spk)``, no per-tick HBM streams.
     ``stream="dma"`` runs the double-buffered event-streaming variant
-    (quiet tick blocks neither fetched nor projected; bit-exact)."""
+    (quiet tick blocks neither fetched nor projected; bit-exact).
+    ``adapt`` runs an ALIF layer (``n_spk`` per population)."""
     return _rsnn.rsnn_infer(
         raster, valid, w_in, w_rec, w_out,
         alpha=alpha, kappa=kappa, v_th=v_th, reset=reset, quant=quant,
         infer_window=infer_window, vmem_budget=vmem_budget,
         batch_tile=batch_tile, stream=stream, interpret=_interpret(),
+        adapt=adapt,
     )
 
 
@@ -129,7 +132,7 @@ def rsnn_step_sessions(
     static_argnames=(
         "alpha", "kappa", "v_th", "reset", "boxcar_width", "quant",
         "error", "target_amplitude", "infer_window", "vmem_budget",
-        "batch_tile", "stream",
+        "batch_tile", "stream", "surrogate", "gamma", "adapt",
     ),
 )
 def rsnn_train(
@@ -153,19 +156,25 @@ def rsnn_train(
     vmem_budget: int = _rsnn.DEFAULT_VMEM_BUDGET,
     batch_tile: Optional[int] = None,
     stream: str = "blocked",
+    surrogate: str = "boxcar",
+    gamma: float = 0.3,
+    adapt: Optional[_rsnn.Adaptation] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """Fused train op: forward + in-kernel readout error + reverse e-prop in
     one two-phase batch-tiled kernel, traces VMEM-resident per tile; any
     batch size runs (tile rows derived from ``vmem_budget``).
     ``stream="dma"`` double-buffers the event blocks (read once, active
-    blocks only) instead of the blocked pipeline's two-phase re-touch."""
+    blocks only) instead of the blocked pipeline's two-phase re-touch.
+    ``adapt`` runs an ALIF layer through the adaptive variant, whose
+    launches are named ``rsnn_train_alif`` (``n_spk`` per population)."""
     return _eprop.rsnn_train(
         raster, y_star, valid, w_in, w_rec, w_out, b_fb,
         alpha=alpha, kappa=kappa, v_th=v_th, reset=reset,
         boxcar_width=boxcar_width, quant=quant, error=error,
         target_amplitude=target_amplitude, infer_window=infer_window,
         vmem_budget=vmem_budget, batch_tile=batch_tile, stream=stream,
-        interpret=_interpret(),
+        interpret=_interpret(), surrogate=surrogate, gamma=gamma,
+        adapt=adapt,
     )
 
 

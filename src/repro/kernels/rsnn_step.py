@@ -29,6 +29,13 @@ Two op-specialized variants live here (one backend op each — see
   tensors to one ``(B,O)`` readout tile plus a ``(B,1)`` spike count — the
   serving hot path.
 
+Adaptive thresholds (ALIF, :class:`Adaptation`): the inference and fused
+train kernels take an optional static ``adapt``.  With it they carry the
+adaptation ``a`` (one more ``(Bt, H)`` scratch), spike against
+``v - beta*a``, and count spikes per population (``n_spk`` is ``(B, 2)``:
+LIF, ALIF).  Without it — every LIF configuration — they are the same
+programs, operand for operand.
+
 ReckOn caps N_in/H at 256 ⇒ weights (256×256 f32 = 256 KiB) sit in VMEM for
 the entire sample.  Batches of any size run as *batch-tiled* grids —
 ``grid = (ceil(B / Bt), T)`` — where the tile rows ``Bt`` are derived from
@@ -40,6 +47,7 @@ and a launch is never capped by VMEM — only its *tiles* are.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Dict, Optional, Tuple
 
@@ -167,15 +175,21 @@ def session_state_bytes(n_hid: int, n_out: int) -> int:
     return _F32 * (2 * n_hid + 2 * n_out + 1)
 
 
-def fused_train_bytes(T: int, B: int, n_in: int, n_hid: int, n_out: int) -> int:
+def fused_train_bytes(T: int, B: int, n_in: int, n_hid: int, n_out: int,
+                      adaptive: bool = False) -> int:
     """VMEM bytes the fused train kernel
     (:func:`repro.kernels.eprop_update.rsnn_train`) needs for one ``(T, B)``
     tile: weights + feedback, the forward carry state, the ``(T, B, ·)``
     e-prop trace scratch (h, xbar, pbar, zbar, err — the tensors the
     two-kernel pipeline would round-trip through HBM), the three ``dw``
-    accumulators, and the double-buffered tick input blocks."""
+    accumulators, and the double-buffered tick input blocks.  An ALIF
+    layer (``adaptive``) adds three per-row carries — the adaptation, the
+    reverse G filter and the next tick's pseudo-derivative — and one more
+    spike counter; its per-tick trace set is the LIF one."""
     weights = weights_bytes(n_in, n_hid, n_out) + _F32 * n_hid * n_out  # + b_fb
     carries = _F32 * B * (5 * n_hid + n_in + 2 * n_out + 1)  # v,z,pbar,zbar,f,xbar,y,acc_y,nspk
+    if adaptive:
+        carries += _F32 * B * (3 * n_hid + 1)                # a, g, h[t+1], nspk
     traces = _F32 * T * B * (3 * n_hid + n_in + n_out)       # h,pbar,zbar + xbar + err
     accs = _F32 * (n_in * n_hid + n_hid * n_hid + n_hid * n_out)
     blocks = _F32 * 2 * B * (n_in + 1)                       # raster + valid tick blocks
@@ -215,6 +229,7 @@ def max_fused_train_tile(
     n_hid: int,
     n_out: int,
     vmem_budget: int = DEFAULT_VMEM_BUDGET,
+    adaptive: bool = False,
 ) -> int:
     """Batch rows per tile of the batch-tiled fused train grid
     (``grid = (ceil(B / Bt), 2T)``): the largest ``Bt`` whose whole-trace
@@ -226,8 +241,8 @@ def max_fused_train_tile(
     ``T``) still compiles in practice — there is no fallback pipeline to
     fall back to any more.  Capped by the kernel contract above.
     """
-    fixed = fused_train_bytes(T, 0, n_in, n_hid, n_out)
-    per_row = fused_train_bytes(T, 1, n_in, n_hid, n_out) - fixed
+    fixed = fused_train_bytes(T, 0, n_in, n_hid, n_out, adaptive)
+    per_row = fused_train_bytes(T, 1, n_in, n_hid, n_out, adaptive) - fixed
     b = (vmem_budget - fixed) // per_row
     return _align_rows(int(min(KERNEL_SAMPLE_CAP, b)))
 
@@ -273,6 +288,48 @@ def _pad_batch_axis(x: jax.Array, axis: int, target: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
+@dataclasses.dataclass(frozen=True)
+class Adaptation:
+    """The static description of an ALIF layer's adaptive threshold: the
+    last ``n_adaptive`` neurons spike against ``v_th + beta * a`` and their
+    adaptation decays by ``rho`` a tick (:mod:`repro.core.neuron`).
+    Hashable, so it is a static argument of the jitted ops like
+    :class:`~repro.core.quant.QuantizedMode`."""
+
+    n_adaptive: int
+    beta: float
+    rho: float
+
+    def beta_row(self, n_hid: int) -> jax.Array:
+        """``(1, H)`` threshold increments, built in-kernel (no operand):
+        0 on the LIF lanes, ``beta`` on the last ``n_adaptive``."""
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, n_hid), 1)
+        return jnp.where(lane >= n_hid - self.n_adaptive, self.beta, 0.0
+                         ).astype(jnp.float32)
+
+    def population_matrix(self, n_hid: int) -> jax.Array:
+        """``(H, 2)`` one-hot of each neuron's population (LIF, ALIF):
+        ``z @ P`` gives per-population spike counts (exact: 0/1 operands)."""
+        row = jax.lax.broadcasted_iota(jnp.int32, (n_hid, 2), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (n_hid, 2), 1)
+        return ((row >= n_hid - self.n_adaptive) == (col == 1)
+                ).astype(jnp.float32)
+
+
+def _count_spikes(zv: jax.Array, adapt: Optional[Adaptation]) -> jax.Array:
+    """Per-row spike counts of valid-masked spikes ``zv`` (Bt, H): ``(Bt, 1)``
+    for a LIF layer, ``(Bt, 2)`` per population for an ALIF one."""
+    if adapt is None:
+        return zv.sum(axis=1, keepdims=True)
+    return jnp.dot(zv, adapt.population_matrix(zv.shape[1]),
+                   preferred_element_type=jnp.float32)
+
+
+def count_columns(adapt: Optional[Adaptation]) -> int:
+    """Width of the kernels' ``n_spk`` output: 1, or 2 populations."""
+    return 1 if adapt is None else 2
+
+
 # ---------------------------------------------------------------------------
 # shared tick datapath
 # ---------------------------------------------------------------------------
@@ -293,12 +350,17 @@ def tick_transition(
     reset_sub: bool,
     boxcar_width: float,
     quant: Optional[QuantizedMode],
+    surrogate: str = "boxcar",
+    gamma: float = 0.3,
+    v_shift: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """One LIF + LI tick on the MXU/VPU — the datapath every RSNN kernel
     (forward, inference-only, fused train) shares.
 
-    Returns ``(v_new, z_new, y_new, h)`` with ``h`` the boxcar
-    pseudo-derivative evaluated at the pre-reset membrane.
+    Returns ``(v_new, z_new, y_new, h)`` with ``h`` the pseudo-derivative
+    (boxcar, or Bellec's triangle) evaluated at the pre-reset membrane —
+    less ``v_shift`` (``beta * a``, an ALIF layer's threshold rise) when
+    given, which the spike test reads too.
 
     Quantized mode runs the same MXU pipeline on integer values carried in
     f32 (all exact below 2**24); ``Precision.HIGHEST`` keeps the dots exact
@@ -310,7 +372,8 @@ def tick_transition(
     return tick_from_input_current(
         in_cur, v, z, y, w_rec, w_out,
         alpha=alpha, kappa=kappa, v_th=v_th, reset_sub=reset_sub,
-        boxcar_width=boxcar_width, quant=quant,
+        boxcar_width=boxcar_width, quant=quant, surrogate=surrogate,
+        gamma=gamma, v_shift=v_shift,
     )
 
 
@@ -328,6 +391,9 @@ def tick_from_input_current(
     reset_sub: bool,
     boxcar_width: float,
     quant: Optional[QuantizedMode],
+    surrogate: str = "boxcar",
+    gamma: float = 0.3,
+    v_shift: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """:func:`tick_transition` with the input projection hoisted out — the
     entry point of the event-driven paths, where ``x_t @ w_in`` is either
@@ -347,12 +413,19 @@ def tick_from_input_current(
     else:
         # sat(floor(v * alpha_reg/256) + current) on the signed membrane grid
         v_pre = quant.sat(quant.leak(v, quant.alpha_reg) + current)
-    z_new = (v_pre >= v_th).astype(v_pre.dtype)
+    v_eff = v_pre if v_shift is None else v_pre - v_shift
+    z_new = (v_eff >= v_th).astype(v_pre.dtype)
     if reset_sub:
         v_new = v_pre - z_new * v_th
     else:
         v_new = v_pre * (1.0 - z_new)
-    h = (jnp.abs(v_pre - v_th) < boxcar_width * v_th).astype(v_pre.dtype)
+    if surrogate == "boxcar":
+        h = (jnp.abs(v_eff - v_th) < boxcar_width * v_th).astype(v_pre.dtype)
+    elif surrogate == "triangular":
+        h = gamma * jnp.maximum(0.0, 1.0 - jnp.abs(v_eff - v_th) / v_th
+                                ).astype(v_pre.dtype)
+    else:
+        raise ValueError(f"unknown surrogate {surrogate!r}")
 
     y_lin = jnp.dot(z_new, w_out, preferred_element_type=jnp.float32,
                     precision=precision)
@@ -438,6 +511,21 @@ def _stream_events(bitmap_ref, raster_hbm, ev_scr, sem, *, s, total, T, bt,
         dma(s, s % 2).wait()
 
     return active, s % 2
+
+
+def _adaptation_in(adapt: Optional[Adaptation], adapt_scr, n_hid: int):
+    """``(a, beta * a)`` of this tick for an ALIF kernel (its first extra
+    scratch ref holds ``a``); ``(None, None)`` for a LIF one."""
+    if adapt is None:
+        return None, None
+    a = adapt_scr[0][...]
+    return a, adapt.beta_row(n_hid) * a
+
+
+def _adaptation_out(adapt: Optional[Adaptation], adapt_scr, a, z_new) -> None:
+    """Store ``a <- rho * a + z`` (ALIF kernels only)."""
+    if adapt is not None:
+        adapt_scr[0][...] = adapt.rho * a + z_new
 
 
 # ---------------------------------------------------------------------------
@@ -764,8 +852,8 @@ def _infer_kernel(
     z_scr,        # VMEM (B, H)
     y_scr,        # VMEM (B, O)
     acc_scr,      # VMEM (B, O)
-    nspk_scr,     # VMEM (B, 1)
-    *,
+    nspk_scr,     # VMEM (B, 1) — (B, 2) per population with adapt
+    *adapt_scr,   # VMEM (B, H) adaptation a — with adapt only
     alpha: float,
     kappa: float,
     v_th: float,
@@ -773,6 +861,7 @@ def _infer_kernel(
     quant: Optional[QuantizedMode],
     infer_all: bool,
     T: int,
+    adapt: Optional[Adaptation] = None,
 ):
     t = pl.program_id(1)   # tick within the current batch tile
 
@@ -784,23 +873,27 @@ def _infer_kernel(
         y_scr[...] = jnp.zeros_like(y_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
         nspk_scr[...] = jnp.zeros_like(nspk_scr)
+        for r in adapt_scr:
+            r[...] = jnp.zeros_like(r)
 
     x_t = raster_ref[0]
     valid_t = valid_ref[0]                     # (B, 1)
+    a, v_shift = _adaptation_in(adapt, adapt_scr, w_rec_ref.shape[0])
 
     v_new, z_new, y_new, _ = tick_transition(
         x_t, v_scr[...], z_scr[...], y_scr[...],
         w_in_ref[...], w_rec_ref[...], w_out_ref[...],
         alpha=alpha, kappa=kappa, v_th=v_th, reset_sub=reset_sub,
-        boxcar_width=0.5, quant=quant,
+        boxcar_width=0.5, quant=quant, v_shift=v_shift,
     )
     v_scr[...] = v_new
     z_scr[...] = z_new
     y_scr[...] = y_new
+    _adaptation_out(adapt, adapt_scr, a, z_new)
 
     w_inf = 1.0 if infer_all else valid_t
     acc_scr[...] += y_new * w_inf
-    nspk_scr[...] += (z_new * valid_t).sum(axis=1, keepdims=True)
+    nspk_scr[...] += _count_spikes(z_new * valid_t, adapt)
 
     # flush this batch tile's accumulators into its (Bt, ·) output blocks
     @pl.when(t == T - 1)
@@ -826,7 +919,7 @@ def _infer_dma_kernel(
     cur_scr,      # VMEM (B, H) — this tick's input current (zeros if quiet)
     ev_scr,       # VMEM (2, B, N_in) — the double buffer
     sem,          # DMA semaphores (2,)
-    *,
+    *adapt_scr,   # VMEM (B, H) adaptation a — with adapt only
     alpha: float,
     kappa: float,
     v_th: float,
@@ -836,6 +929,7 @@ def _infer_dma_kernel(
     T: int,
     nb: int,
     bt: int,
+    adapt: Optional[Adaptation] = None,
 ):
     """:func:`_infer_kernel` with double-buffered event streaming and the
     in-kernel quiet-tick skip — the event-driven serving hot path."""
@@ -850,6 +944,8 @@ def _infer_dma_kernel(
         y_scr[...] = jnp.zeros_like(y_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
         nspk_scr[...] = jnp.zeros_like(nspk_scr)
+        for r in adapt_scr:
+            r[...] = jnp.zeros_like(r)
 
     active, slot = _stream_events(
         bitmap_ref, raster_hbm, ev_scr, sem, s=s, total=nb * T, T=T, bt=bt
@@ -867,19 +963,21 @@ def _infer_dma_kernel(
         cur_scr[...] = jnp.zeros_like(cur_scr)
 
     valid_t = valid_ref[0]                     # (B, 1)
+    a, v_shift = _adaptation_in(adapt, adapt_scr, w_rec_ref.shape[0])
     v_new, z_new, y_new, _ = tick_from_input_current(
         cur_scr[...], v_scr[...], z_scr[...], y_scr[...],
         w_rec_ref[...], w_out_ref[...],
         alpha=alpha, kappa=kappa, v_th=v_th, reset_sub=reset_sub,
-        boxcar_width=0.5, quant=quant,
+        boxcar_width=0.5, quant=quant, v_shift=v_shift,
     )
     v_scr[...] = v_new
     z_scr[...] = z_new
     y_scr[...] = y_new
+    _adaptation_out(adapt, adapt_scr, a, z_new)
 
     w_inf = 1.0 if infer_all else valid_t
     acc_scr[...] += y_new * w_inf
-    nspk_scr[...] += (z_new * valid_t).sum(axis=1, keepdims=True)
+    nspk_scr[...] += _count_spikes(z_new * valid_t, adapt)
 
     @pl.when(t == T - 1)
     def _flush():
@@ -904,6 +1002,7 @@ def rsnn_infer(
     batch_tile: Optional[int] = None,
     stream: str = "blocked",
     interpret: bool = False,
+    adapt: Optional[Adaptation] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Inference-only forward over one ``(T, B)`` launch — the serving path.
 
@@ -914,7 +1013,9 @@ def rsnn_infer(
     count entirely in VMEM and streams **no** per-tick tensors.  Returns
     ``(acc_y (B, O), n_spk (B, 1))`` — in quantized mode both are exact
     integers carried in f32 (bit-identical to the golden reference's
-    accumulators, see ``tests/test_quant_equivalence.py``).
+    accumulators, see ``tests/test_quant_equivalence.py``).  With ``adapt``
+    (an ALIF layer, float only) the kernel carries the adaptation and
+    ``n_spk`` is ``(B, 2)``: LIF and ALIF spikes.
     """
     T, B, n_in = raster.shape
     H = w_rec.shape[0]
@@ -939,17 +1040,24 @@ def rsnn_infer(
         infer_all=(infer_window == "all"),
         T=T,
     )
+    nc = count_columns(adapt)
     out_shape = [
         jax.ShapeDtypeStruct((b_pad, O), dt),
-        jax.ShapeDtypeStruct((b_pad, 1), dt),
+        jax.ShapeDtypeStruct((b_pad, nc), dt),
     ]
     carry_scratch = [
         pltpu.VMEM((bt, H), jnp.float32),
         pltpu.VMEM((bt, H), jnp.float32),
         pltpu.VMEM((bt, O), jnp.float32),
         pltpu.VMEM((bt, O), jnp.float32),
-        pltpu.VMEM((bt, 1), jnp.float32),
+        pltpu.VMEM((bt, nc), jnp.float32),
     ]
+    adapt_scratch = []
+    if adapt is not None:
+        if quant is not None:
+            raise ValueError("adaptive thresholds are float-only")
+        consts["adapt"] = adapt
+        adapt_scratch = [pltpu.VMEM((bt, H), jnp.float32)]   # a
 
     if stream == "dma":
         bitmap, raster, w_in = _dma_operands(raster, w_in, bt)
@@ -970,13 +1078,13 @@ def rsnn_infer(
             ],
             out_specs=[
                 pl.BlockSpec((bt, O), lambda b, t, s_ref: (b, 0)),
-                pl.BlockSpec((bt, 1), lambda b, t, s_ref: (b, 0)),
+                pl.BlockSpec((bt, nc), lambda b, t, s_ref: (b, 0)),
             ],
             scratch_shapes=carry_scratch + [
                 pltpu.VMEM((bt, H), jnp.float32),        # input current
                 pltpu.VMEM((2, bt, n_in), jnp.float32),  # event double buffer
                 pltpu.SemaphoreType.DMA((2,)),
-            ],
+            ] + adapt_scratch,
         )
         acc_y, n_spk = pl.pallas_call(
             kern, grid_spec=grid_spec, out_shape=out_shape,
@@ -999,10 +1107,10 @@ def rsnn_infer(
             ],
             out_specs=[
                 pl.BlockSpec((bt, O), lambda b, t: (b, 0)),
-                pl.BlockSpec((bt, 1), lambda b, t: (b, 0)),
+                pl.BlockSpec((bt, nc), lambda b, t: (b, 0)),
             ],
             out_shape=out_shape,
-            scratch_shapes=carry_scratch,
+            scratch_shapes=carry_scratch + adapt_scratch,
             interpret=interpret,
         )(raster, valid, w_in, w_rec, w_out)
     return acc_y[:B], n_spk[:B]

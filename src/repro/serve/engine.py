@@ -76,6 +76,7 @@ from repro.core.backend import (
     ExecutionBackend,
     RuntimeConfig,
 )
+from repro.core.neuron import AdaptationUnsupported
 from repro.core.rsnn import RSNNConfig
 from repro.serve import batching
 from repro.serve.guard import (
@@ -943,8 +944,18 @@ class BatchedEngine:
         device time: a session whose deadline passes before its pending
         ticks are packed is dropped at pack time with a terminal EXPIRED
         snapshot.
+
+        A model with adaptive-threshold (ALIF) neurons serves whole samples
+        only: opening a session on it raises
+        :class:`~repro.core.neuron.AdaptationUnsupported`.
         """
         lane = self._lane(model_id)
+        if lane.cfg.neuron.adaptive:
+            raise AdaptationUnsupported(
+                f"model {lane.model_id!r} has adaptive-threshold neurons: "
+                "the session carry (v, z, y, acc_y, n_spk) has no adaptation, "
+                "so it serves whole samples (submit/serve) only"
+            )
         sess = _Session(
             self._next_sid, self._clock(), meta, model_id=lane.model_id
         )
@@ -1335,7 +1346,12 @@ class BatchedEngine:
         zero carries in (one cached pytree per tile width), carries out
         unobserved — and skips the session pool entirely: whole-sample
         serving pays no pool-sized scatter and no per-request host
-        bookkeeping."""
+        bookkeeping.
+
+        An ALIF layer has no session carry: its tiles run through the
+        inference op instead (same decode, zero state, every tick live)."""
+        if lane.cfg.neuron.adaptive:
+            return self._launch_tile(lane, tile)
         self._inject_fault(lane, "tile")
         cfg = lane.cfg
         T = tile.num_ticks
@@ -1528,6 +1544,8 @@ class BatchedEngine:
         jax.block_until_ready(
             lane.backend.inference(lane.weights, raster, valid)["acc_y"]
         )
+        if lane.cfg.neuron.adaptive:
+            return        # no session program for an ALIF layer
         state = lane.backend.init_session_state(b)
         jax.block_until_ready(
             lane.backend.step_sessions(

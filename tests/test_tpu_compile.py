@@ -168,3 +168,33 @@ def test_data_parallel_backend_compiles_for_2x2(topo, monkeypatch):
         per_device = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                       + mem.temp_size_in_bytes)
         assert 0 < per_device < 16 * 2**30
+
+
+@pytest.mark.parametrize("stream", ["blocked", "dma"])
+@pytest.mark.parametrize("op", ["train", "infer"])
+def test_adaptive_kernels_compile_for_v5e(one_chip, op, stream):
+    """The ALIF variants of the fused train kernel and of the inference
+    kernel at the LSNN configuration: 40-100-2 with 50 ALIF neurons,
+    T = 2,250, a batch of 64, tile rows as the planner gives them."""
+    cfg = Presets.lsnn_evidence()
+    n = cfg.neuron
+    adapt = R.Adaptation(n.n_adaptive, n.beta, n.rho)
+    N, H, O, T, B = cfg.n_in, cfg.n_hid, cfg.n_out, cfg.num_ticks, 64
+    bt = R.max_fused_train_tile(T, N, H, O, adaptive=True)
+    assert bt == 8
+
+    def S(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    kw = dict(alpha=n.alpha, kappa=n.kappa, v_th=n.v_th, stream=stream,
+              adapt=adapt)
+    w = [S(N, H), S(H, H), S(H, O)]
+    if op == "train":
+        fn = lambda r, ys, v, *w: E.rsnn_train(
+            r, ys, v, *w, surrogate=n.surrogate, gamma=n.gamma, **kw)
+        args = [S(T, B, N), S(B, O), S(T, B), *w, S(H, O)]
+    else:
+        fn = lambda r, v, *w: R.rsnn_infer(r, v, *w, **kw)
+        args = [S(T, B, N), S(T, B), *w]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
