@@ -85,6 +85,7 @@ from repro.serve.guard import (
     OverloadError,
     QuotaExceededError,
     ServeStatus,
+    _validate_counting,
     bad_rows,
     validate_events,
 )
@@ -992,16 +993,15 @@ class BatchedEngine:
         """The feed's guard: the words' validity and the session's spike
         quota.  Returns the validated words; a refusal counts as rejected."""
         try:
-            events = validate_events(
+            events, incoming = _validate_counting(
                 events, lane.guard,
                 min_tick=max(sess.max_fed_tick, 0),
-                what=f"session {sess.sid} feed",
+                what=lambda: f"session {sess.sid} feed",
             )
         except GuardError:
             lane.rejected += 1
             raise
         backlog = len(sess.sp_tick) - sess.sp_ptr
-        incoming = int(np.count_nonzero(events >> 24 == 0x03))
         if backlog + incoming > lane.guard.max_pending_events:
             lane.rejected += 1
             raise QuotaExceededError(
