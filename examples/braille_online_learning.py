@@ -6,8 +6,7 @@ the AER-decoder loop trains on each sample as it streams through.
 ``--commit sample`` (default) updates weights at every end-of-sample — true
 online learning; ``--commit batch`` runs each offloaded batch as one
 rectangular tile through the execution backend and commits the summed
-update at the END_B boundary (multi-x faster, see
-``benchmarks/bench_braille.py --smoke``).
+update at the END_B boundary (fewer, larger launches).
 
     PYTHONPATH=src python examples/braille_online_learning.py \
         [--classes AEU|SAEU|AEOU] [--epochs 50] [--commit sample|batch] [--quant]
@@ -47,7 +46,7 @@ def main():
     cfg = Presets.braille(n_classes=len(SUBSETS[opts.classes]),
                           num_ticks=data["train"]["num_ticks"])
     opt_cfg = EpropSGDConfig(
-        # batch commits take a tuned 2x lr (see bench_braille._opt_cfg)
+        # batch commits take a tuned 2x lr (as in tests/test_accuracy_parity.py)
         lr=0.01 if opts.commit == "sample" else 0.02, clip=10.0,
         quant=WEIGHT_SPEC if opts.quant else None,
         stochastic_round=opts.quant,

@@ -16,8 +16,7 @@ kernels, ``"scan"`` = reference ``lax.scan``):
 * ``commit="batch"`` (END_B, ARM mode): the whole BRAM-sized batch runs as
   one rectangular ``(T, B, N)`` tile through the backend's fused forward +
   e-prop update, and the batch-summed ``dw`` commits once at the batch
-  boundary (:func:`make_batch_commit_train_fn`) — the high-throughput mode
-  ``benchmarks/bench_braille.py`` measures against the sequential loop;
+  boundary (:func:`make_batch_commit_train_fn`) — the high-throughput mode;
 * the EPOCH_ACC counter sampled by the ILA is the ``correct`` counter folded
   through the scan.
 
@@ -36,8 +35,8 @@ config is quantization-aware training instead (float master weights,
 quantized datapath).
 
 Inference entries: :func:`make_infer_fn` is the *sequential* per-sample
-classify (the FSM's TEST=1 walk, and the baseline
-``benchmarks/bench_serve.py`` measures against);
+classify (the FSM's TEST=1 walk, and the reference the serving tests hold
+the batched engine to);
 :func:`make_batch_infer_fn` is its batch-capable twin.  The batched serving
 runtime (:mod:`repro.serve.engine`) no longer owns its own dispatch — it
 drives the same :class:`~repro.core.backend.ExecutionBackend` object, which
@@ -281,8 +280,6 @@ def make_infer_fn(cfg: RSNNConfig):
     """Sequential single-sample classify — the chip's one-at-a-time TEST walk.
 
     ``fn(weights, raster (T, N_in), valid (T,)) -> {"acc_y" (O,), "pred" ()}``.
-    ``benchmarks/bench_serve.py`` uses this as the baseline the batched
-    engine is measured against.
     """
     batched = make_batch_infer_fn(cfg)
 

@@ -131,6 +131,17 @@ def _datapath(params: Dict[str, jax.Array], ncfg: NeuronConfig, ecfg: EpropConfi
     )
 
 
+def _rowwise_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b`` as a broadcast product summed over the contraction axis.
+
+    Each output row is reduced in an order fixed by ``b``'s shape alone, so
+    a row's value does not depend on how many rows share the call or where
+    it sits among them.  A host GEMM blocks its rows by the row count, so
+    the same row can round differently in calls of different heights; this
+    form does not."""
+    return (a[..., :, None] * b).sum(axis=-2)
+
+
 def _input_projection(
     raster: jax.Array, w_in_d: jax.Array, dot,
     sparse_rows: int | None = None,
@@ -543,7 +554,11 @@ def run_stream_inference(
     construction in the host packer).  Chunking is carry-exact: feeding the
     same ticks in any chunking yields bit-identical final state on this
     backend (asserted in ``tests/test_streaming.py``, bit-true against the
-    integer golden reference in quantized mode).
+    integer golden reference in quantized mode).  In float mode every
+    contraction is :func:`_rowwise_dot`, so a session's values do not depend
+    on how many sessions share its tile or how many ticks the tile holds:
+    the bucketed whole-sample serve and any feed chunking round alike.
+    Quantized mode keeps its ``HIGHEST`` dots, exact on integers.
     """
     if ncfg.adaptive:
         raise AdaptationUnsupported(
@@ -556,6 +571,8 @@ def run_stream_inference(
     alpha = jnp.broadcast_to(jnp.asarray(params["alpha"], dtype), (H,))
     kappa = jnp.asarray(ncfg.kappa, dtype)
     w_in_d, w_rec_d, w_out_d, _, _, dot = _datapath(params, ncfg, ecfg)
+    if ncfg.quant is None:
+        dot = _rowwise_dot
 
     in_cur = _input_projection(raster, w_in_d, dot, sparse_rows)
     acc_all = ecfg.infer_window == "all"

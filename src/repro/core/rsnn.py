@@ -107,16 +107,6 @@ def merge_trainable(
     return out
 
 
-def param_count(cfg: RSNNConfig) -> int:
-    return cfg.n_in * cfg.n_hid + cfg.n_hid * cfg.n_hid + cfg.n_hid * cfg.n_out
-
-
-def sram_bytes(cfg: RSNNConfig, weight_bits: int = 8) -> int:
-    """Weight-SRAM footprint in bytes — the TPU analog of the BRAM columns in
-    the paper's Tables 1/2 (used by ``benchmarks/bench_resources.py``)."""
-    return param_count(cfg) * weight_bits // 8
-
-
 @dataclasses.dataclass(frozen=True)
 class Presets:
     """The two experimental networks of the paper, and the LSNN of e-prop's

@@ -56,9 +56,9 @@ def event_density(events, n_in: Optional[int] = None,
     the buffers decode to.
 
     This is the *ground truth* behind the "~2-5% on Braille" figure: the
-    traffic gates (``benchmarks/bench_kernels.py``) and the backend's
-    dense/event dispatch (:func:`repro.kernels.events.resolve_sparsity`)
-    both consume this measurement instead of assuming a constant.
+    backend's dense/event dispatch
+    (:func:`repro.kernels.events.resolve_sparsity`) consumes this
+    measurement instead of assuming a constant.
 
     ``events`` is either a padded ``(S, L)`` uint32 word matrix plus
     explicit ``n_in`` / ``num_ticks``, or a dataset split dict
@@ -82,7 +82,7 @@ def event_density(events, n_in: Optional[int] = None,
 
 @dataclasses.dataclass
 class PipelineStats:
-    """Telemetry for the resource benchmark (Tables 1/2 analog).
+    """Pipeline telemetry.
 
     ``decodes`` and ``decode_programs`` give the decode's program reuse:
     batches decoded, and distinct ``(words shape, dtype, n_in, num_ticks,
@@ -91,8 +91,6 @@ class PipelineStats:
     same shapes counts its own but compiles nothing new).
     """
 
-    h2d_bytes: int = 0        # host→device traffic issued
-    resident_bytes: int = 0   # device-resident dataset footprint
     transfers: int = 0        # number of device_put calls
     decodes: int = 0          # batches decoded
     decode_programs: int = 0  # distinct decode programs among them
@@ -127,14 +125,10 @@ class ResidentPipeline(_Base):
         self._resident: Dict[str, DeviceBatch] = {}
         for split, d in dataset.items():
             words = jax.device_put(jnp.asarray(d["events"]))
-            self.stats.h2d_bytes += d["events"].nbytes
             self.stats.transfers += 1
             batch = self._decode(words, d)
             batch = jax.tree.map(jax.device_put, batch)
             self._resident[split] = batch
-            self.stats.resident_bytes += sum(
-                x.nbytes for x in jax.tree.leaves(batch)
-            ) + d["events"].nbytes
 
     def batches(self, split: str, epoch: int,
                 start_batch: int = 0) -> Iterator[DeviceBatch]:
@@ -197,7 +191,6 @@ class BatchedOffloadPipeline(_Base):
 
     def _offload(self, chunk: np.ndarray, meta: Dict) -> DeviceBatch:
         words = jax.device_put(jnp.asarray(chunk))   # async dispatch
-        self.stats.h2d_bytes += chunk.nbytes
         self.stats.transfers += 1
         return self._decode(words, meta)
 

@@ -1,8 +1,7 @@
 """Training-side kernels: the factored e-prop update and the fused
 forward+update train kernel.
 
-Two variants serve the backend's training ops (see the data-movement table
-in :mod:`repro.kernels.traffic` / README):
+Two variants serve the backend's training ops:
 
 * :func:`eprop_update` — the split-pipeline reverse pass.  Consumes the
   per-tick traces :func:`repro.kernels.rsnn_step.rsnn_forward` streamed to

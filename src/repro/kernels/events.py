@@ -66,14 +66,6 @@ def row_density(density: float, n_in: int) -> float:
     return float(1.0 - (1.0 - float(density)) ** int(n_in))
 
 
-def block_density(density: float, rows: int, n_in: int) -> float:
-    """Expected fraction of *active* ``(batch-tile, tick)`` event blocks of
-    ``rows`` samples — what the DMA-streamed kernels' HBM fetch scales with.
-    Collapses to :func:`row_density` at ``rows == 1`` (the edge single-stream
-    operating point, where tick-skip bites hardest)."""
-    return float(1.0 - (1.0 - float(density)) ** (int(rows) * int(n_in)))
-
-
 def suggest_row_capacity(
     T: int,
     B: int,
@@ -137,8 +129,8 @@ def sparse_input_projection(
     a ``lax.cond`` runs the dense projection instead — in-graph, no host
     sync, results unchanged (just no savings for that launch).
 
-    Returns ``(proj (T, B, H), n_active ())`` — the count is what the
-    traffic accounting and the benches record as the as-executed density.
+    Returns ``(proj (T, B, H), n_active ())`` — the count of active rows,
+    the launch's as-executed density.
     """
     if dot is None:
         dot = jnp.matmul

@@ -14,8 +14,7 @@ MAC loop into two MXU matmuls per tick:
             trace filters (α, κ)                   (VPU)
 
 Two op-specialized variants live here (one backend op each — see
-:mod:`repro.core.backend` and the data-movement table in
-``kernels/traffic.py`` / README):
+:mod:`repro.core.backend`):
 
 * :func:`rsnn_forward` — serves the ``forward_traces`` and ``dynamics`` ops.
   Streams the per-tick quantities the *split* factored e-prop update needs
@@ -119,8 +118,8 @@ def vmem_laid_out_bytes(*shapes: Tuple[int, ...]) -> int:
 
 
 def weight_elems(n_in: int, n_hid: int, n_out: int) -> int:
-    """Elements in the weight set (w_in + w_rec + w_out) — shared by the
-    VMEM budget below and the HBM traffic table (:mod:`repro.kernels.traffic`)."""
+    """Elements in the weight set (w_in + w_rec + w_out), for the VMEM
+    budget below."""
     return n_in * n_hid + n_hid * n_hid + n_hid * n_out
 
 

@@ -1,10 +1,10 @@
 """Where JAX's persistent compilation cache lives.
 
-Entry points (``chip_smoke.py``, ``benchmarks/run.py``) call
-:func:`enable_compile_cache` once at startup; importing a library module
-never touches the cache.  The directory is fixed — never a tempdir, PID or
-timestamp — because only a run that looks in the same directory finds the
-programs an earlier run compiled.
+The entry point ``chip_smoke.py`` calls :func:`enable_compile_cache` once
+at startup; importing a library module never touches the cache.  The
+directory is fixed — never a tempdir, PID or timestamp — because only a run
+that looks in the same directory finds the programs an earlier run
+compiled.
 """
 
 from __future__ import annotations

@@ -45,11 +45,6 @@ def make_data_mesh(n_data: int | None = None):
     return _auto_mesh((n,), ("data",))
 
 
-# TPU v5e hardware constants for the roofline model (per chip).
-PEAK_FLOPS_BF16 = 197e12          # FLOP/s
-HBM_BW = 819e9                    # B/s
-ICI_BW = 50e9                     # B/s per link (~3 usable links per axis)
-DCN_BW = 25e9                     # B/s per host-ish (cross-pod; coarse)
 # Physical VMEM per core: compiling for a described v5e, Mosaic accepts
 # 127 MiB of kernel scratch and refuses 129 MiB against this size.  A
 # pipelined kernel gets a 16 MiB scoped slice of it unless its

@@ -26,8 +26,7 @@ scale.
 * :mod:`repro.serve.engine`    — the engine, session handles, stats.
 
 See ``docs/serving.md`` for the session lifecycle and the migration guide
-from the whole-sample API, ``benchmarks/bench_serve.py --streaming`` for
-the sustained-throughput gate, and ``examples/streaming_sessions.py`` /
+from the whole-sample API, and ``examples/streaming_sessions.py`` /
 ``examples/serve_braille.py`` for end-to-end demos.
 
 This package re-exports exactly the supported public surface (``__all__``

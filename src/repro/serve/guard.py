@@ -33,8 +33,8 @@ This module is the serving path's trust boundary:
   quarantined while the rest of its tile delivers bitwise-unchanged.
 
 See ``docs/serving.md`` ("Hardened serving") for the operator-facing
-semantics and ``benchmarks/bench_chaos.py --serve`` for the chaos gate that
-exercises all of it at once.
+semantics and ``tests/test_robustness.py`` for the fuzz and fault drills
+that exercise all of it at once.
 """
 
 from __future__ import annotations

@@ -21,12 +21,12 @@ The harness has two halves:
   A worker that reaches the configured epochs writes its final quantized
   weights (npz) + a result manifest (json) to ``--out`` and exits 0.
 
-* **driver** (:func:`run_chaos`, used by ``tests/test_fault_tolerance.py``
-  and ``benchmarks/bench_chaos.py``) — spawns the worker with a kill flag,
-  watches it die, then respawns it *without* kill flags until it exits
-  clean; :func:`golden_run` produces the uninterrupted reference weights
-  in-process, :func:`golden_worker` in a worker on the drill's own
-  platform.  Bitwise comparison is the caller's one-line job.
+* **driver** (:func:`run_chaos`, used by ``tests/test_fault_tolerance.py``)
+  — spawns the worker with a kill flag, watches it die, then respawns it
+  *without* kill flags until it exits clean; :func:`golden_run` produces
+  the uninterrupted reference weights in-process, :func:`golden_worker` in
+  a worker on the drill's own platform.  Bitwise comparison is the
+  caller's one-line job.
 
 Determinism contract that makes the bitwise gate possible: batch order is
 pure in ``(seed, epoch)`` (:mod:`repro.data.pipeline`), the stochastic-

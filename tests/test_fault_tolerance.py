@@ -461,9 +461,8 @@ def test_chaos_sigkill_at_commit_boundary(tmp_path):
 
 
 def test_golden_worker_matches_in_process_golden(tmp_path):
-    """The worker-hosted golden (what bench_chaos compares against, so the
-    gate's two sides share the workers' pinned platform) lands bitwise on
-    the in-process golden."""
+    """The worker-hosted golden (so a drill's two sides can share the
+    workers' pinned platform) lands bitwise on the in-process golden."""
     gold = chaos.golden_run(**GOLD_KW)
     got = chaos.golden_worker(
         str(tmp_path / "ck"), str(tmp_path / "golden"), WARGS

@@ -17,7 +17,6 @@ from repro.core.backend import ExecutionBackend
 from repro.core.eprop import EpropConfig
 from repro.core.neuron import NeuronConfig
 from repro.core.rsnn import Presets, RSNNConfig, init_params, trainable
-from repro.kernels import traffic
 from repro.kernels.rsnn_step import (
     DEFAULT_VMEM_BUDGET,
     KERNEL_SAMPLE_CAP,
@@ -436,12 +435,3 @@ def test_fused_train_budget_scales_with_tile():
     assert fused_train_bytes(200, 16, n, h, o) > fused_train_bytes(100, 16, n, h, o)
     assert fused_train_bytes(100, 32, n, h, o) > fused_train_bytes(100, 16, n, h, o)
 
-
-def test_traffic_table_ratios_hold_across_shapes():
-    """The data-movement claims gate CI: ≥2x less train traffic, ≥3x less
-    serve traffic — at the bench tile and at chip-maximal shape."""
-    for shape in [(100, 16, 40, 100, 2), (256, 128, 256, 256, 16),
-                  (32, 1, 12, 38, 3)]:
-        t = traffic.op_table(*shape)
-        assert t["train_two_kernel"] / t["train_fused"] >= 2.0, shape
-        assert t["infer_streamed"] / t["infer_fused"] >= 3.0, shape

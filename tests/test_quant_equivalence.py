@@ -186,8 +186,9 @@ def test_quantized_serving_engine_matches_golden():
 def test_quantized_online_learning_improves():
     """End-to-end chip-faithful training (quantized datapath + stochastic
     8-bit SRAM commits) learns on the reduced Braille task.  ``slow``: the
-    CI fast lane covers the same loop via ``bench_braille --quant --smoke``
-    in the quant-smoke job; this runs in the full suite / quant lane."""
+    CI fast lane covers the same loop through
+    ``tests/test_accuracy_parity.py``; this runs in the full suite / quant
+    lane."""
     from repro.core.controller import ControllerConfig, OnlineLearner
     from repro.core.quant import WEIGHT_SPEC
     from repro.data.braille import BrailleConfig, make_braille_dataset

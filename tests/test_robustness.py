@@ -6,8 +6,8 @@ overload; deadlines drop work at pack time (before any launch); numeric
 health checks quarantine one poisoned session while its tile-mates deliver
 bitwise-unchanged; and a faulted lane restarts — rebuilt backend, sessions
 re-seated from bit-exact eviction snapshots — with final results bitwise
-equal to an undisturbed run.  ``benchmarks/bench_chaos.py --serve`` runs
-the same machinery under sustained fuzz/fault/overload storms.
+equal to an undisturbed run, also under mid-stream fuzz and repeated
+launch faults on the scan, quantized and kernel datapaths.
 """
 
 import jax
@@ -327,6 +327,67 @@ def test_stream_fault_budget_quarantines_sessions():
     h2 = eng.open_session()
     h2.feed(reqs[1])
     assert h2.result().status is ServeStatus.OK
+
+
+def _stream_halves(eng, reqs, abuse=None):
+    """Feed every session its events in two halves, one pump per round,
+    with ``abuse(eng, handles)`` between the feeds and the pump; the final
+    snapshots in session order."""
+    handles = [eng.open_session() for _ in reqs]
+    for step in range(2):
+        for h, ev in zip(handles, reqs):
+            mid = len(ev) // 2
+            h.feed(ev[:mid] if step == 0 else ev[mid:])
+        if abuse is not None:
+            abuse(eng, handles)
+        eng.pump()
+    eng.pump(drain=True)
+    return [h.result() for h in handles]
+
+
+@pytest.mark.parametrize(
+    "backend,quantized",
+    [("scan", False), ("scan", True), ("kernel", False)],
+    ids=["scan-float", "scan-quant", "kernel"],
+)
+def test_fuzz_and_launch_faults_mid_stream_leave_sessions_bitwise(
+    backend, quantized
+):
+    """Mid-stream, raw 32-bit noise is fed to one live session and submitted
+    as a whole sample, and every third streaming launch fails.  Every noise
+    buffer is refused with a typed error, the lane restarts, and every
+    session ends bitwise equal to an undisturbed run."""
+    cfg, params, reqs = _setup(seed=0, n=6, T=48, quantized=quantized)
+    rng = np.random.default_rng(0)
+    kw = dict(backend=backend, max_batch=4, tick_tile=8)
+    clean = _stream_halves(BatchedEngine(cfg, params, **kw), reqs)
+
+    refused = []
+
+    def fuzz(eng, handles):
+        for _ in range(8):
+            noise = rng.integers(0, 2**32, int(rng.integers(1, 32)), np.uint32)
+            with pytest.raises(aer.AEREncodingError):
+                handles[0].feed(noise)
+            refused.append(noise)
+        with pytest.raises(aer.AEREncodingError):
+            eng.submit(rng.integers(0, 2**32, 16, np.uint32))
+
+    fuzzed = _stream_halves(BatchedEngine(cfg, params, **kw), reqs, fuzz)
+    assert len(refused) == 16
+
+    eng = BatchedEngine(
+        cfg, params, fault_hook=_flaky_hook(set(range(3, 100, 3)),
+                                            kinds=("stream",)), **kw
+    )
+    faulted = _stream_halves(eng, reqs)
+    assert eng.stream_stats(1.0).lane_restarts >= 1
+
+    for got in (fuzzed, faulted):
+        for g, w in zip(got, clean):
+            assert g.status is w.status is ServeStatus.OK
+            assert g.pred == w.pred
+            np.testing.assert_array_equal(g.logits, w.logits)
 
 
 @pytest.mark.parametrize("path", ["serve", "pump"])
